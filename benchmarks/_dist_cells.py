@@ -50,10 +50,8 @@ def main(argv=None):
     tiny = "--tiny" in (argv or sys.argv[1:])
     n_dev = len(jax.devices())
     assert n_dev == 8, jax.devices()
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,)
-    mesh = jax.make_mesh((n_dev,), ("data",), **kwargs)
+    mesh = jax.make_mesh((n_dev,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     cfg = F.FCMConfig(max_iters=300)
     reps = 1 if tiny else 3
     size = 64 if tiny else 128

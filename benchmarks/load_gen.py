@@ -181,9 +181,8 @@ def run_load_gen(tiny: bool = False, route: str = "histogram",
                 "--mesh needs >1 device; set XLA_FLAGS="
                 "--xla_force_host_platform_device_count=8 before jax "
                 "initializes")
-        kwargs = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-                  if hasattr(jax.sharding, "AxisType") else {})
-        dev_mesh = jax.make_mesh((n_dev,), ("data",), **kwargs)
+        dev_mesh = jax.make_mesh((n_dev,), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
     # tracing=False drops the debug span ring, not the serving
     # telemetry: queue-depth gauges, batch-occupancy, latency and
     # deadline counters all live on the metrics registry and keep
@@ -282,6 +281,8 @@ def main(argv=None):
                          "of every local device (needs >1 device)")
     args = ap.parse_args(argv)
 
+    import repro
+    repro.enable_compile_cache()
     try:
         from . import bench_schema
     except ImportError:

@@ -120,6 +120,8 @@ def main(argv=None):
 
     import jax
 
+    import repro
+    repro.enable_compile_cache()
     from . import (batched_throughput, bench_schema, fig7_dsc, load_gen,
                    roofline_report, spatial_fcm, superpixel_fcm, sweep,
                    table1_variants, table3_speedup)

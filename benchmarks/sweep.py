@@ -406,9 +406,12 @@ def _distributed_cells(tiny: bool) -> List[Dict[str, Any]]:
     script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "_dist_cells.py")
     cmd = [_sys.executable, script] + (["--tiny"] if tiny else [])
+    # The child fakes 8 CPU devices; it must never reach for the chip
+    # this process holds.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     try:
         out = subprocess.run(cmd, capture_output=True, text=True,
-                             timeout=1800, check=True)
+                             timeout=1800, check=True, env=env)
         payload = json.loads(out.stdout.strip().splitlines()[-1])
     except Exception as e:
         return [{"cell_id": cell_id("distributed",

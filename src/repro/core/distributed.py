@@ -29,23 +29,12 @@ from . import fcm as F
 from . import histogram as H
 from . import solver as SV
 
-try:                                  # jax >= 0.6 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:                # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: older jax has no replication rule for
-    ``while`` and needs ``check_rep=False``; newer jax renamed/removed
-    the flag. Our bodies run while_loops, so disable the check wherever
-    the installed jax still spells it ``check_rep``."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    """``jax.shard_map`` with the varying-axes check off: the bodies run
+    ``while_loop``s whose carries mix per-device and replicated values,
+    which the check rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def mesh_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -72,10 +61,14 @@ def pad_to_devices(x, n_devices: int):
 
 
 def masked_center_step(x, w, v, m):
-    """Fused v->v' step with a validity mask (local partial sums only)."""
+    """Fused v->v' step with a validity mask (local partial sums only).
+    The numerator is a broadcast-multiply-sum, as in
+    :func:`repro.core.solver.weighted_center_step`: ``um @ x`` would run
+    at the TPU's default matmul precision, which rounds f32 operands
+    to bf16."""
     u = F.update_membership(x, v, m)          # (c, n_local)
     um = (u ** m) * w[None, :]
-    num = um @ x                              # (c,)
+    num = jnp.sum(um * x[None, :], axis=1)    # (c,)
     den = jnp.sum(um, axis=1)                 # (c,)
     return num, den
 
@@ -167,5 +160,7 @@ def fit_sharded(x, mesh: Mesh, cfg: F.FCMConfig = F.FCMConfig(),
     fit = (build_sharded_histogram_fit if histogram
            else build_sharded_fit)(mesh, cfg)
     v, labels, delta, it = fit(xp, w)
-    return F.FCMResult(centers=v, labels=labels[:n], n_iters=int(it),
-                       final_delta=float(delta))
+    # Unpad on the host: on a mesh with explicit axes (``jax.make_mesh``'s
+    # default) slicing the sharded labels would need an output sharding.
+    return F.FCMResult(centers=v, labels=jax.device_get(labels)[:n],
+                       n_iters=int(it), final_delta=float(delta))

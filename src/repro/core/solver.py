@@ -193,11 +193,12 @@ class FCMProblem:
     def n_rows(self) -> Optional[int]:
         """Problem size the registry's VMEM-residency bounds are
         checked against: the row count of a flat problem, or the
-        per-lane PIXEL count of a stencil problem (what the resident
-        stencil solve must hold in VMEM)."""
+        per-lane padded PIXEL count of a stencil problem (what the
+        resident stencil solve must hold in VMEM)."""
         lead = 1 if self.batch else 0
         if self.stencil is not None:
-            return int(np.prod(self.features.shape[lead:]))
+            from repro.kernels.fcm_resident import stencil_pixels
+            return stencil_pixels(self.features.shape[lead:])
         return int(self.features.shape[lead])
 
     def rows(self) -> Tuple[jax.Array, jax.Array]:

@@ -19,7 +19,7 @@ to solo :func:`repro.core.solver.while_centers` runs, with no frozen-lane
 masking work at all.
 
 Rows are tiled ``(D, R, 128)`` per lane with zero-weight padding;
-centers travel lane-broadcast as ``(c, D, 128)`` blocks.
+centers travel lane-broadcast as ``(c, D, 1, 128)`` blocks.
 
 Two residency extensions lift the whole-solve shape to real workloads:
 
@@ -71,42 +71,105 @@ STREAM_MAX_ROWS = 131072
 
 #: Resident stencil bounds: the padded grid, validity sheet, the
 #: hoisted neighborhood fields and the (c, *grid) membership
-#: temporaries must all sit in VMEM: ~(6 + 4c) * pixels * 4 bytes,
-#: about 10 MiB at the c=8 / 64k-pixel corner.
+#: temporaries must all sit in VMEM. The bound counts *padded* pixels
+#: (:func:`stencil_pixels`): a thin image can pad to many times its
+#: own size. At the c=8 / 64k-pixel corner the v5e compiler asks for
+#: 16.7 MiB, past the 16 MiB default scoped-VMEM limit, so the kernel
+#: raises its limit to ``STENCIL_VMEM_BYTES``.
 STENCIL_MAX_PIXELS = 65536
 STENCIL_MAX_C = 8
+STENCIL_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def stencil_pixels(grid_shape) -> int:
+    """Pixels of one lane's grid after ``ops.tile_grid_batched`` pads H
+    to 8 and W to 128 (what the resident stencil solve holds in VMEM
+    and what its dispatch bound counts)."""
+    *lead, h, w = grid_shape
+    n = (h + (-h) % 8) * (w + (-w) % LANES)
+    for d in lead:
+        n *= d
+    return int(n)
+
+
+# Mosaic lays a block's two minor dims out as (8, 128) tiles, needs them
+# aligned or spanning the array, and rejects 1-D vectors that a
+# reduction produces and a broadcast reuses. So every in-kernel value
+# here keeps rank >= 2: reductions keep their dims, centers travel as
+# (c, D, 1, 128) lane-replicated blocks and are carried as (c, D, 1, 1),
+# and the per-lane scalars (tol in; delta, iters out) travel as
+# (B, 1, 128) arrays in (1, 1, 128) blocks.
+
+def _lane_scalar_spec():
+    return pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0))
+
+
+def _lane_scalar_shape(b: int, dtype):
+    return jax.ShapeDtypeStruct((b, 1, LANES), dtype)
+
+
+def _lane_scalars(a: jax.Array) -> jax.Array:
+    """(B,) -> (B, 1, 128) lane-replicated float32."""
+    return jnp.broadcast_to(a.astype(jnp.float32)[:, None, None],
+                            (a.shape[0], 1, LANES))
+
+
+def _converge(step, v0, tol_ref, max_iters, v_ref, delta_ref, it_ref):
+    """Run the solver core's stop test in-kernel and write the lane's
+    centers (broadcast over the 128 lanes), residual and iterations."""
+    from repro.core.solver import while_centers
+    v, delta, it = while_centers(step, v0, tol_ref[0, 0, 0], max_iters)
+    v_ref[...] = jnp.broadcast_to(v[None], v_ref.shape)
+    delta_ref[...] = jnp.full(delta_ref.shape, delta, jnp.float32)
+    it_ref[...] = jnp.full(it_ref.shape, it, jnp.int32)
+
+
+def _lane_sum(a: jax.Array) -> jax.Array:
+    return jnp.sum(a, axis=-1, keepdims=True)
+
+
+def _row_partials(x, w, v, m: float):
+    """Eq. 3 partials of rows ``x`` (D, R, 128) weighted by ``w``
+    (R, 128) at centers ``v`` (c, D, 1, 1): ``num`` (c, D, 1, 128) and
+    ``den`` (c, 1, 1, 128), summed over rows but not yet over lanes."""
+    d2 = jnp.sum((v - x[None]) ** 2, axis=1)         # (c, R, 128)
+    um = (membership_from_d2_tile(d2, m) ** m) * w[None]
+    num = jnp.sum(um[:, None] * x[None], axis=2, keepdims=True)
+    den = jnp.sum(um[:, None], axis=2, keepdims=True)
+    return num, den
+
+
+def _centers(num, den):
+    return _lane_sum(num) / jnp.maximum(_lane_sum(den), _D2_FLOOR)
 
 
 def _resident_kernel(x_ref, w_ref, v0_ref, tol_ref,
                      v_ref, delta_ref, it_ref, *, m: float, max_iters: int):
-    x = x_ref[...][0].astype(jnp.float32)            # (D, R, 128)
-    w = w_ref[...][0].astype(jnp.float32)            # (R, 128)
-    v0 = v0_ref[...][0, :, :, 0].astype(jnp.float32)  # (c, D)
-    tol = tol_ref[...][0, 0]
+    x = x_ref[0].astype(jnp.float32)                  # (D, R, 128)
+    w = w_ref[0].astype(jnp.float32)                  # (R, 128)
+    v0 = v0_ref[0][..., :1].astype(jnp.float32)       # (c, D, 1, 1)
+    _converge(lambda v: _centers(*_row_partials(x, w, v, m)), v0, tol_ref,
+              max_iters, v_ref, delta_ref, it_ref)
 
-    def step(v):
-        d2 = jnp.sum((v[:, :, None, None] - x[None, :, :, :]) ** 2, axis=1)
-        u = membership_from_d2_tile(d2, m)           # (c, R, 128)
-        um = (u ** m) * w[None, :, :]
-        den = jnp.sum(um, axis=(1, 2))               # (c,)
-        num = jnp.sum(um[:, None, :, :] * x[None, :, :, :], axis=(2, 3))
-        return num / jnp.maximum(den, _D2_FLOOR)[:, None]
 
-    def cond(state):
-        _, delta, it = state
-        return jnp.logical_and(delta >= tol, it < max_iters)
+def _center_blocks(v0: jax.Array) -> jax.Array:
+    """(B, c, D) -> (B, c, D, 1, 128) lane-replicated center blocks."""
+    b, c, d = v0.shape
+    return jnp.broadcast_to(v0.astype(jnp.float32)[..., None, None],
+                            (b, c, d, 1, LANES))
 
-    def body(state):
-        v, _, it = state
-        v_new = step(v)
-        return v_new, jnp.max(jnp.abs(v_new - v)), it + 1
 
-    v, delta, it = jax.lax.while_loop(
-        cond, body, (v0, jnp.asarray(jnp.inf, jnp.float32),
-                     jnp.asarray(0, jnp.int32)))
-    v_ref[...] = jnp.broadcast_to(v[None, :, :, None], v_ref.shape)
-    delta_ref[...] = jnp.broadcast_to(delta, delta_ref.shape)
-    it_ref[...] = jnp.broadcast_to(it, it_ref.shape)
+def _center_spec(c: int, d: int):
+    return pl.BlockSpec((1, c, d, 1, LANES), lambda i: (i, 0, 0, 0, 0))
+
+
+def _solve_out(b: int, c: int, d: int):
+    """out_specs / out_shape of the flat whole-solve kernels."""
+    specs = [_center_spec(c, d), _lane_scalar_spec(), _lane_scalar_spec()]
+    shapes = [jax.ShapeDtypeStruct((b, c, d, 1, LANES), jnp.float32),
+              _lane_scalar_shape(b, jnp.float32),
+              _lane_scalar_shape(b, jnp.int32)]
+    return specs, shapes
 
 
 def resident_solve_pallas(x4: jax.Array, w3: jax.Array, v0: jax.Array,
@@ -118,31 +181,21 @@ def resident_solve_pallas(x4: jax.Array, w3: jax.Array, v0: jax.Array,
     lane run to its own convergence inside one kernel launch."""
     b, d, r, _ = x4.shape
     c = v0.shape[1]
-    v0b = jnp.broadcast_to(v0.astype(jnp.float32)[..., None], (b, c, d, LANES))
-    tolb = jnp.broadcast_to(tol.astype(jnp.float32)[:, None], (b, LANES))
-    grid = (b,)
+    out_specs, out_shape = _solve_out(b, c, d)
     v, delta, it = pl.pallas_call(
         partial(_resident_kernel, m=m, max_iters=max_iters),
-        grid=grid,
+        grid=(b,),
         in_specs=[
             pl.BlockSpec((1, d, r, LANES), lambda i: (i, 0, 0, 0)),
             pl.BlockSpec((1, r, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, c, d, LANES), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
+            _center_spec(c, d),
+            _lane_scalar_spec(),
         ],
-        out_specs=[
-            pl.BlockSpec((1, c, d, LANES), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, c, d, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, LANES), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(x4, w3, v0b, tolb)
-    return v[..., 0], delta[:, 0], it[:, 0]
+    )(x4, w3, _center_blocks(v0), _lane_scalars(tol))
+    return v[..., 0, 0], delta[:, 0, 0], it[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -154,9 +207,8 @@ def _streamed_kernel(x_hbm, w_hbm, v0_ref, tol_ref,
                      xbuf, wbuf, xsem, wsem,
                      *, m: float, max_iters: int, n_chunks: int):
     lane = pl.program_id(0)
-    v0 = v0_ref[...][0, :, :, 0].astype(jnp.float32)  # (c, D)
-    tol = tol_ref[...][0, 0]
-    c, d = v0.shape
+    v0 = v0_ref[0][..., :1].astype(jnp.float32)      # (c, D, 1, 1)
+    c, d = v0.shape[:2]
     chunk = xbuf.shape[2]                             # (2, D, chunk, 128)
 
     def copies(k, slot):
@@ -177,7 +229,6 @@ def _streamed_kernel(x_hbm, w_hbm, v0_ref, tol_ref,
             cp.start()
 
         def chunk_body(k, acc):
-            num, den = acc
             slot = jax.lax.rem(k, 2)
             nxt = jax.lax.rem(k + 1, 2)
 
@@ -188,35 +239,16 @@ def _streamed_kernel(x_hbm, w_hbm, v0_ref, tol_ref,
 
             for cp in copies(k, slot):
                 cp.wait()
-            x = xbuf[slot]                         # (D, chunk, 128)
-            w = wbuf[slot]                         # (chunk, 128)
-            d2 = jnp.sum((v[:, :, None, None] - x[None]) ** 2, axis=1)
-            u = membership_from_d2_tile(d2, m)     # (c, chunk, 128)
-            um = (u ** m) * w[None]
-            den = den + jnp.sum(um, axis=(1, 2))
-            num = num + jnp.sum(um[:, None] * x[None], axis=(2, 3))
-            return num, den
+            num, den = _row_partials(xbuf[slot], wbuf[slot], v, m)
+            return acc[0] + num, acc[1] + den
 
         num, den = jax.lax.fori_loop(
             0, n_chunks, chunk_body,
-            (jnp.zeros((c, d), jnp.float32), jnp.zeros((c,), jnp.float32)))
-        return num / jnp.maximum(den, _D2_FLOOR)[:, None]
+            (jnp.zeros((c, d, 1, LANES), jnp.float32),
+             jnp.zeros((c, 1, 1, LANES), jnp.float32)))
+        return _centers(num, den)
 
-    def cond(state):
-        _, delta, it = state
-        return jnp.logical_and(delta >= tol, it < max_iters)
-
-    def body(state):
-        v, _, it = state
-        v_new = step(v)
-        return v_new, jnp.max(jnp.abs(v_new - v)), it + 1
-
-    v, delta, it = jax.lax.while_loop(
-        cond, body, (v0, jnp.asarray(jnp.inf, jnp.float32),
-                     jnp.asarray(0, jnp.int32)))
-    v_ref[...] = jnp.broadcast_to(v[None, :, :, None], v_ref.shape)
-    delta_ref[...] = jnp.broadcast_to(delta, delta_ref.shape)
-    it_ref[...] = jnp.broadcast_to(it, it_ref.shape)
+    _converge(step, v0, tol_ref, max_iters, v_ref, delta_ref, it_ref)
 
 
 def resident_streamed_solve_pallas(x4: jax.Array, w3: jax.Array,
@@ -233,30 +265,19 @@ def resident_streamed_solve_pallas(x4: jax.Array, w3: jax.Array,
         raise ValueError(f"streamed solve needs R % {STREAM_CHUNK_ROWS} "
                          f"== 0, got R={r} (pad with tile_rows_batched("
                          f"..., rows_multiple=STREAM_CHUNK_ROWS))")
-    n_chunks = r // STREAM_CHUNK_ROWS
-    v0b = jnp.broadcast_to(v0.astype(jnp.float32)[..., None],
-                           (b, c, d, LANES))
-    tolb = jnp.broadcast_to(tol.astype(jnp.float32)[:, None], (b, LANES))
+    out_specs, out_shape = _solve_out(b, c, d)
     v, delta, it = pl.pallas_call(
         partial(_streamed_kernel, m=m, max_iters=max_iters,
-                n_chunks=n_chunks),
+                n_chunks=r // STREAM_CHUNK_ROWS),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((1, c, d, LANES), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            _center_spec(c, d),
+            _lane_scalar_spec(),
         ],
-        out_specs=[
-            pl.BlockSpec((1, c, d, LANES), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, c, d, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, LANES), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((2, d, STREAM_CHUNK_ROWS, LANES), jnp.float32),
             pltpu.VMEM((2, STREAM_CHUNK_ROWS, LANES), jnp.float32),
@@ -264,8 +285,9 @@ def resident_streamed_solve_pallas(x4: jax.Array, w3: jax.Array,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(x4.astype(jnp.float32), w3.astype(jnp.float32), v0b, tolb)
-    return v[..., 0], delta[:, 0], it[:, 0]
+    )(x4.astype(jnp.float32), w3.astype(jnp.float32), _center_blocks(v0),
+      _lane_scalars(tol))
+    return v[..., 0, 0], delta[:, 0, 0], it[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -287,15 +309,22 @@ def _shift_grid(a: jax.Array, off) -> jax.Array:
     return jnp.pad(a, pads)[tuple(slices)]
 
 
+def _grid_sum(a: jax.Array) -> jax.Array:
+    """(c, *grid) -> (c, 1, 1): major grid axes first, then the two
+    minor ones one at a time with their dims kept (Mosaic aborts on a
+    keepdims reduction over both at once)."""
+    while a.ndim > 3:
+        a = jnp.sum(a, axis=1)
+    return jnp.sum(jnp.sum(a, axis=2, keepdims=True), axis=1, keepdims=True)
+
+
 def _resident_stencil_kernel(x_ref, valid_ref, v0_ref, tol_ref,
                              v_ref, delta_ref, it_ref, *, m: float,
                              alpha: float, offsets, max_iters: int):
-    x = x_ref[...][0].astype(jnp.float32)          # (Hp, Wp) / (D, Hp, Wp)
-    valid = valid_ref[...][0].astype(jnp.float32)
-    v0 = v0_ref[...][0, :, 0].astype(jnp.float32)  # (c,)
-    tol = tol_ref[...][0, 0]
+    x = x_ref[0].astype(jnp.float32)               # (Hp, Wp) / (D, Hp, Wp)
+    valid = valid_ref[0].astype(jnp.float32)
+    v0 = v0_ref[0][..., :1].astype(jnp.float32)    # (c, 1, 1)
     c = v0.shape[0]
-    axes = tuple(range(1, 1 + x.ndim))
 
     # Iteration-invariant neighborhood fields. The validity sheet plays
     # the border role: padding pixels carry valid=0 and x=0, so shifts
@@ -323,25 +352,10 @@ def _resident_stencil_kernel(x_ref, valid_ref, v0_ref, tol_ref,
             nb = nb + _shift_grid(d2v, (0,) + tuple(off))
         u = membership_from_d2_tile(d2 + alpha * (nb / cnt[None]), m)
         um = (u ** m) * valid[None]
-        den = jnp.sum(um, axis=axes)               # (c,)
-        num = jnp.sum(um * x_eff[None], axis=axes)
-        return num / jnp.maximum(den, _D2_FLOOR)
+        return _grid_sum(um * x_eff[None]) / jnp.maximum(_grid_sum(um),
+                                                         _D2_FLOOR)
 
-    def cond(state):
-        _, delta, it = state
-        return jnp.logical_and(delta >= tol, it < max_iters)
-
-    def body(state):
-        v, _, it = state
-        v_new = step(v)
-        return v_new, jnp.max(jnp.abs(v_new - v)), it + 1
-
-    v, delta, it = jax.lax.while_loop(
-        cond, body, (v0, jnp.asarray(jnp.inf, jnp.float32),
-                     jnp.asarray(0, jnp.int32)))
-    v_ref[...] = jnp.broadcast_to(v[None, :, None], v_ref.shape)
-    delta_ref[...] = jnp.broadcast_to(delta, delta_ref.shape)
-    it_ref[...] = jnp.broadcast_to(it, it_ref.shape)
+    _converge(step, v0, tol_ref, max_iters, v_ref, delta_ref, it_ref)
 
 
 def resident_stencil_solve_pallas(xpad: jax.Array, vpad: jax.Array,
@@ -358,30 +372,23 @@ def resident_stencil_solve_pallas(xpad: jax.Array, vpad: jax.Array,
     grid_shape = xpad.shape[1:]
     c = v0.shape[1]
     offsets = neighbor_offsets(len(grid_shape), neighbors)
-    v0b = jnp.broadcast_to(v0.astype(jnp.float32)[..., None], (b, c, LANES))
-    tolb = jnp.broadcast_to(tol.astype(jnp.float32)[:, None], (b, LANES))
     gblock = (1,) + grid_shape
     gmap = (lambda i: (i,) + (0,) * len(grid_shape))
+    vspec = pl.BlockSpec((1, c, 1, LANES), lambda i: (i, 0, 0, 0))
     v, delta, it = pl.pallas_call(
         partial(_resident_stencil_kernel, m=m, alpha=alpha,
                 offsets=offsets, max_iters=max_iters),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec(gblock, gmap),
-            pl.BlockSpec(gblock, gmap),
-            pl.BlockSpec((1, c, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, c, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, c, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, LANES), jnp.int32),
-        ],
+        in_specs=[pl.BlockSpec(gblock, gmap), pl.BlockSpec(gblock, gmap),
+                  vspec, _lane_scalar_spec()],
+        out_specs=[vspec, _lane_scalar_spec(), _lane_scalar_spec()],
+        out_shape=[jax.ShapeDtypeStruct((b, c, 1, LANES), jnp.float32),
+                   _lane_scalar_shape(b, jnp.float32),
+                   _lane_scalar_shape(b, jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=STENCIL_VMEM_BYTES),
         interpret=interpret,
-    )(xpad.astype(jnp.float32), vpad.astype(jnp.float32), v0b, tolb)
-    return v[..., 0], delta[:, 0], it[:, 0]
+    )(xpad.astype(jnp.float32), vpad.astype(jnp.float32),
+      jnp.broadcast_to(v0.astype(jnp.float32)[..., None, None],
+                       (b, c, 1, LANES)), _lane_scalars(tol))
+    return v[:, :, 0, 0], delta[:, 0, 0], it[:, 0, 0]

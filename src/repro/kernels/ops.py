@@ -607,8 +607,9 @@ def _stencil_resident(xpad, vpad, m, alpha, neighbors, max_iters,
                       interpret=None, **_):
     """VMEM-resident whole-solve FCM_S: the complete Eq. 4'/Eq. 3'
     fixed point of every lane runs inside one kernel (inputs from
-    :func:`tile_grid_batched`; ``max_rows`` bounds the per-lane PIXEL
-    count — ``FCMProblem.n_rows`` reports it for stencil problems).
+    :func:`tile_grid_batched`; ``max_rows`` bounds the per-lane padded
+    PIXEL count of :func:`~repro.kernels.fcm_resident.stencil_pixels` —
+    ``FCMProblem.n_rows`` reports it for stencil problems).
     Returns a ``(v0, tol) -> (v, delta, iters)`` solver like the other
     resident builders."""
     if interpret is None:

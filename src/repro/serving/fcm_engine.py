@@ -71,6 +71,7 @@ from repro.core import fcm as F
 from repro.core import solver as SV
 from repro.core import spatial as SP
 from repro.core.batched import hist_rows
+from repro.kernels import fcm_resident as KR
 from repro.kernels import ops as kops
 from repro.superpixel import pipeline as SX
 
@@ -231,12 +232,15 @@ class RouteProgram:
     stats, convergence telemetry and the LRU cache see exactly what the
     staged path would have produced (the trailing per-lane residual is
     optional: pre-telemetry programs returning 4-tuples still run).
+    ``impls`` names the registry kernels the launch resolved
+    (``"kind/impl"``), reported by ``stats()["route_impls"]``.
     """
     gather: Callable[["FCMServeEngine", List[Any], int], Tuple]
     launch: Callable[..., Tuple]
     scatter: Callable[["FCMServeEngine", List[Any], Tuple],
                       Tuple[List[SegmentationResult], np.ndarray,
                             np.ndarray, int]]
+    impls: Tuple[str, ...] = ()
 
 
 #: Module-level cache of *compiled* launch functions, keyed on the full
@@ -481,7 +485,9 @@ def _make_histogram_program(eng, key, bucket) -> RouteProgram:
                    for i, p in enumerate(chunk)]
             return res, centers, iters_np, int(total), np.asarray(delta)
 
-        return RouteProgram(gather, launch, scatter)
+        return RouteProgram(gather, launch, scatter,
+                            (("bin/pallas",) if on_tpu else ())
+                            + (f"flat/{impl}",))
 
     # Mixed payload sizes: one solve dispatch on the stacked histograms,
     # per-request labels via the (cheap) host LUT gather.
@@ -502,7 +508,7 @@ def _make_histogram_program(eng, key, bucket) -> RouteProgram:
                for i, p in enumerate(chunk)]
         return res, centers, iters_np, int(total), np.asarray(delta)
 
-    return RouteProgram(gather, launch, scatter)
+    return RouteProgram(gather, launch, scatter, (f"flat/{impl}",))
 
 
 # -- pixel route ------------------------------------------------------------
@@ -608,7 +614,8 @@ def _make_pixel_program(eng, key, bucket) -> RouteProgram:
                for i, q in enumerate(chunk)]
         return res, centers, iters_np, int(total), np.asarray(delta)
 
-    return RouteProgram(gather, launch, scatter)
+    return RouteProgram(gather, launch, scatter,
+                        (f"flat/{impl}", f"labels/{labels_impl}"))
 
 
 # -- spatial route ----------------------------------------------------------
@@ -685,7 +692,7 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
     eps, max_iters = float(scfg.eps), int(scfg.max_iters)
     platform = jax.default_backend()
     impl = kops.select_step("stencil", platform=platform, batched=True,
-                            n_rows=int(np.prod(shape)), c=c).name
+                            n_rows=KR.stencil_pixels(shape), c=c).name
 
     def launch_fn(imgs):
         v, delta, iters, total = SV.stencil_batched_solve(
@@ -722,7 +729,7 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
                for i, q in enumerate(chunk)]
         return res, centers, iters_np, int(total), np.asarray(delta)
 
-    return RouteProgram(gather, launch, scatter)
+    return RouteProgram(gather, launch, scatter, (f"stencil/{impl}",))
 
 
 # -- superpixel route -------------------------------------------------------
@@ -1936,6 +1943,12 @@ class FCMServeEngine:
                      "materialize": s[r.stat("materialize")]}
             for r in ROUTES.values()}
         s["compiled_programs"] = len(self._programs)
+        # Which registry kernels the compiled programs resolved, per
+        # route: the device path a deployment actually serves on.
+        impls: Dict[str, set] = {}
+        for key, prog in list(self._programs.items()):
+            impls.setdefault(key[0], set()).update(prog.impls)
+        s["route_impls"] = {name: sorted(v) for name, v in impls.items()}
         # Per-route submit->result latency percentiles and convergence
         # mix — the two new observability blocks.
         s["latency"] = {r.name: self._latency_hist(r.name).snapshot()

@@ -26,9 +26,10 @@ Two assignment implementations drive the same loop:
   (``use_pallas=True``), which tiles pixels into row blocks with the
   whole (small) center grid resident in VMEM.
 
-Both accumulate the distance terms in the same order, so interpret-mode
-parity is exact up to genuine distance ties (which both resolve to the
-lowest center index). They are registered in the
+Both accumulate the distance terms in the same order and resolve ties
+to the lowest center index, so they agree except where a pixel's two
+best candidates lie within a few ulp, which two separately compiled
+programs may round either way. They are registered in the
 :mod:`repro.kernels.ops` dispatch registry under kind ``"slic_assign"``;
 ``use_pallas=None`` lets the registry pick by platform.
 """
@@ -214,6 +215,9 @@ def fit_slic(img, params: SLICParams = SLICParams(),
     if use_pallas is None:
         from repro.kernels import ops as kops
         use_pallas = kops.select_step("slic_assign").name == "pallas"
+    from repro import obs
+    obs.default_registry().counter(
+        "slic.fits", impl="pallas" if use_pallas else "reference").inc()
     img = _as_hwd(img)
     h, w, d = img.shape
     gy, gx = grid_shape(h, w, params.n_segments)

@@ -20,11 +20,8 @@ from repro.data import phantom  # noqa: E402
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    # axis_types only exists on newer jax; explicit-Auto is its default.
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    mesh = jax.make_mesh((4, 2), ("data", "model"), **kwargs)
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     img, _ = phantom.phantom_slice(256, 256, seed=11)
     x = img.ravel().astype(np.float32)
 

@@ -28,10 +28,8 @@ def _check_same(a, b):
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,)
-    mesh = jax.make_mesh((8,), ("data",), **kwargs)
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     cfg = F.FCMConfig(max_iters=300)
     # bucket 8 divides the mesh; bucket 1 exercises the single-device
     # fallback inside a meshed engine (mesh does not divide the bucket).
@@ -63,8 +61,7 @@ def main():
 
     # A one-device mesh is the degenerate single-device path.
     one = jax.make_mesh((1,), ("data",),
-                        **({"axis_types": (jax.sharding.AxisType.Auto,)}
-                           if hasattr(jax.sharding, "AxisType") else {}))
+                        axis_types=(jax.sharding.AxisType.Auto,))
     meshed.set_mesh(one)
     for a, b in zip(meshed.segment(imgs), ref):
         _check_same(a, b)

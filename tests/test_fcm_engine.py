@@ -522,6 +522,25 @@ def test_program_cache_evicts_on_route_reregistration():
         E.register_route(base)
 
 
+def test_stats_report_the_kernels_each_route_resolved():
+    """route_impls names what every compiled program launches, so a
+    deployment can tell the device path from a reference fallback."""
+    from repro.kernels import ops as kops
+
+    img, _ = phantom.phantom_slice(32, 32, seed=4)
+    eng = FCMServeEngine(CFG, batch_sizes=(1,), cache_size=0)
+    assert eng.stats()["route_impls"] == {}
+    eng.segment([img])
+    eng.segment([img], method="pixel")
+    eng.segment([img], method="spatial")
+    flat = kops.select_step("flat", batched=True, n_rows=img.size, c=4)
+    assert eng.stats()["route_impls"] == {
+        "histogram": [f"flat/{flat.name}"],
+        "pixel": [f"flat/{flat.name}",
+                  f"labels/{kops.select_step('labels').name}"],
+        "spatial": [f"stencil/{kops.select_step('stencil').name}"]}
+
+
 def test_stage_seconds_breakdown_in_stats():
     eng = FCMServeEngine(CFG)
     s = eng.stats()["stage_seconds"]

@@ -31,18 +31,43 @@ def _assign_both(img, centers, gy, gx, sw):
     return ref, ker
 
 
+def _joint_d2(img, centers, sw, ys, xs, ks):
+    """Joint distance of pixels (ys, xs) to centers ks, in float32 and
+    in the order both implementations accumulate it."""
+    d = img.shape[2]
+    c = np.asarray(centers, np.float32)[ks]
+    px = img[ys, xs]
+    d2 = np.zeros(len(ys), np.float32)
+    for ch in range(d):
+        d2 = d2 + (px[:, ch] - c[:, ch]) ** 2
+    d2 = d2 + np.float32(sw) * (ys.astype(np.float32) - c[:, d]) ** 2
+    d2 = d2 + np.float32(sw) * (xs.astype(np.float32) - c[:, d + 1]) ** 2
+    return d2
+
+
 @pytest.mark.parametrize("h,w,segs", SHAPES)
 @pytest.mark.parametrize("channels", [1, 3])
 def test_assignment_step_parity(h, w, segs, channels):
-    """A single assignment step agrees exactly: same candidate sets,
-    same accumulation order, same lowest-index tie resolution."""
+    """A single assignment step agrees: same candidate sets, same
+    accumulation order, same lowest-index tie resolution. The two are
+    separate XLA programs, and the CPU compiler may round one of them
+    differently (fused multiply-adds), so a pixel whose two best
+    candidates lie within a few ulp of each other may go either way.
+    Every disagreeing pixel must be such a near-tie; any other
+    disagreement is a real assignment bug."""
     img = _img(h, w, channels, seed=h + w + channels)
     gy, gx = SL.grid_shape(h, w, segs)
     sw = SL.spatial_weight(h, w, gy, gx, 10.0)
     centers = SL.seed_centers(img, gy, gx)
     ref, ker = _assign_both(img, centers, gy, gx, sw)
     assert ref.shape == ker.shape == (h, w)
-    np.testing.assert_array_equal(ref, ker)
+    ys, xs = np.nonzero(ref != ker)
+    hwd = img if img.ndim == 3 else img[:, :, None]
+    d_ref = _joint_d2(hwd, centers, sw, ys, xs, ref[ys, xs])
+    d_ker = _joint_d2(hwd, centers, sw, ys, xs, ker[ys, xs])
+    ulp = np.spacing(np.maximum(d_ref, d_ker))
+    assert (np.abs(d_ref - d_ker) <= 4 * ulp).all(), \
+        list(zip(ys, xs, d_ref, d_ker))
 
 
 @pytest.mark.parametrize("h,w,segs", SHAPES)
