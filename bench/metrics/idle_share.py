@@ -1,0 +1,7 @@
+"""Device: the share of the traced span in which no operation ran, in
+%, averaged over the chips the cell uses."""
+
+
+def read(ctx):
+    t = ctx.trace
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
