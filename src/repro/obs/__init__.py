@@ -14,7 +14,8 @@ primitives:
   (``with tracer.span("solve", route=...)``) recording wall time and,
   via :meth:`~repro.obs.tracing.Span.fence`, ``block_until_ready``-
   fenced device time; finished root spans land in a ring buffer of the
-  last N trace records.
+  last N trace records, and every span is a ``fcm.<name>`` annotation
+  in the JAX profiler's trace.
 * a module-level default registry (:func:`default_registry`) that the
   solver core records convergence telemetry into — see
   ``repro.core.solver._record_telemetry``.

@@ -14,11 +14,16 @@ and records the span-start -> ready interval as ``device_s`` — the
 fenced device time — while returning the value, so the call site stays
 one expression: ``outs = sp.fence(prog.launch(*inputs))``.
 
+Every span, enabled or not, is also a ``jax.profiler.TraceAnnotation``
+named ``fcm.<name>``, so a profiler trace shows the engine's stages on
+the device's clock (about a microsecond a span with no profiler
+session active). Only the attrs a span names in ``annotate`` ride on
+the annotation; request ids stay in the ring's ``attrs``.
+
 ``Tracer(enabled=False)`` keeps timing semantics (spans still measure,
-``fence`` still blocks) but skips ring-buffer and metrics recording —
-what the tracing-overhead benchmark compares against. With a
-:class:`~repro.obs.metrics.MetricsRegistry` attached, every finished
-span also lands in a ``span_seconds{span=<name>}`` histogram.
+``fence`` still blocks, annotations still reach the profiler) but skips
+the ring buffer — what the tracing-overhead benchmark compares against.
+Callers feed their own counters from ``Span.wall_s``.
 """
 from __future__ import annotations
 
@@ -26,7 +31,9 @@ import collections
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from . import metrics as M
 
@@ -83,12 +90,8 @@ class Tracer:
     """Span factory + ring buffer of the last ``max_traces`` root
     traces. The span stack is thread-local; the ring is shared."""
 
-    def __init__(self, max_traces: int = 64, enabled: bool = True,
-                 metrics: Optional[M.MetricsRegistry] = None,
-                 span_metric: str = "span_seconds"):
+    def __init__(self, max_traces: int = 64, enabled: bool = True):
         self.enabled = enabled
-        self.metrics = metrics
-        self.span_metric = span_metric
         self._ring: Deque[dict] = collections.deque(maxlen=max_traces)
         self._local = threading.local()
 
@@ -104,16 +107,21 @@ class Tracer:
         return stack[-1] if stack else None
 
     @contextmanager
-    def span(self, name: str, ring: bool = True, **attrs):
-        """Open a timed span. ``ring=False`` keeps a root span out of
-        the trace ring (per-submit validation spans would otherwise
-        drown the flush traces) while still timing and feeding metrics.
-        Exceptions mark the span ``status="error"`` and propagate."""
+    def span(self, name: str, ring: bool = True,
+             annotate: Sequence[str] = (), **attrs):
+        """Open a timed span, annotated in the profiler trace as
+        ``fcm.<name>`` with the attrs ``annotate`` names. ``ring=False``
+        keeps a root span out of the trace ring (per-submit validation
+        spans would otherwise drown the flush traces) while still
+        timing. Exceptions mark the span ``status="error"`` and
+        propagate."""
         sp = Span(name, attrs)
         stack = self._stack()
         stack.append(sp)
         try:
-            yield sp
+            with TraceAnnotation("fcm." + name,
+                                 **{k: attrs[k] for k in annotate}):
+                yield sp
         except BaseException as e:
             sp.close(e)
             raise
@@ -125,9 +133,6 @@ class Tracer:
                 stack[-1].children.append(sp)
             elif ring and self.enabled:
                 self._ring.append(sp.to_dict())
-            if self.enabled and self.metrics is not None:
-                self.metrics.histogram(self.span_metric,
-                                       span=name).record(sp.wall_s)
 
     def traces(self) -> List[dict]:
         """The finished root traces, oldest first (plain dicts)."""

@@ -62,6 +62,7 @@ from typing import (Any, Callable, Dict, Hashable, List, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as _P
 
 from repro import faults as FI
@@ -324,6 +325,15 @@ def _jit_launch(eng: "FCMServeEngine", bucket: int, cache_key: Hashable,
 
 
 ROUTES: "collections.OrderedDict[str, RouteSpec]" = collections.OrderedDict()
+
+#: The stages of ``route.stage_seconds{route,stage}``: ``ingest``
+#: (submit-time validation and reduction), ``gather`` (flush-time
+#: stacking of a batch: a program's gather or the staged path's build),
+#: ``solve`` (the fenced launch) and inside it ``h2d`` (the inputs put on
+#: the device), ``materialize`` (unpacking results) and inside it ``d2h``
+#: (the outputs fetched to the host), ``compress`` (superpixel ingest).
+STAGES = ("ingest", "gather", "solve", "h2d", "materialize", "d2h",
+          "compress")
 
 #: Route generations: bumped on every (re-)registration so engine-held
 #: compiled programs for a replaced spec are evicted, never served stale.
@@ -881,12 +891,11 @@ class FCMServeEngine:
         # MetricsRegistry (stats() renders the legacy flat keys from it)
         # plus a Tracer whose ring keeps the last ``trace_ring`` flush
         # traces. ``tracing=False`` keeps every stats counter (they are
-        # the backward-compatible API) but skips ring-buffer and
-        # span-histogram recording — the knob the tracing-overhead
+        # the backward-compatible API) and the profiler annotations but
+        # skips ring-buffer recording — the knob the tracing-overhead
         # benchmark toggles.
         self.metrics = obs.MetricsRegistry()
-        self.tracer = obs.Tracer(max_traces=trace_ring, enabled=tracing,
-                                 metrics=self.metrics)
+        self.tracer = obs.Tracer(max_traces=trace_ring, enabled=tracing)
         # -- fault tolerance ------------------------------------------------
         #: bounded retry on transient launch failures (exponential
         #: backoff: retry_backoff_s * 2^attempt between attempts).
@@ -957,8 +966,9 @@ class FCMServeEngine:
                       "degraded", "breaker_trips", "invalid_input"):
                 self._route_counter(k, route.name)
             self.metrics.gauge("route.breaker_state", route=route.name)
-            for stage in ("ingest", "solve", "materialize", "compress"):
+            for stage in STAGES:
                 self._stage_seconds(route.name, stage)
+            self._queue_wait(route.name)
             self._latency_hist(route.name)
             self._iters_hist(route.name)
             self._occupancy_hist(route.name)
@@ -1010,6 +1020,12 @@ class FCMServeEngine:
     def _stage_seconds(self, route_name: str, stage: str) -> obs.Counter:
         return self.metrics.counter("route.stage_seconds",
                                     route=route_name, stage=stage)
+
+    def _queue_wait(self, route_name: str) -> obs.Counter:
+        """Per-route seconds requests spent queued: submit -> the start
+        of their batch's gather, summed over batched lanes."""
+        return self.metrics.counter("route.queue_wait_seconds",
+                                    route=route_name)
 
     def _latency_hist(self, route_name: str) -> obs.Histogram:
         """Per-route submit->result latency (seconds)."""
@@ -1623,18 +1639,30 @@ class FCMServeEngine:
                 self._set_breaker(route_name, b, "open")
 
     def _launch_attempts(self, route: RouteSpec, prog: RouteProgram,
-                         inputs: Tuple) -> Tuple:
+                         inputs: Tuple, bucket: int) -> Tuple[Tuple, float]:
         """One program launch under the bounded-retry policy: transient
         failures (injected faults, launch-time runtime errors) retry up
         to ``retries`` times with exponential backoff; programming
         errors (ValueError/TypeError) and the final failure propagate —
-        the caller advances the breaker and degrades the chunk."""
+        the caller advances the breaker and degrades the chunk. Each
+        attempt first puts the host ``inputs`` on the device with the
+        launch's sharding (an ``h2d`` span, fenced: the batch axis split
+        over the mesh ``_jit_launch`` shards this bucket over, so no
+        reshard runs inside the launch); returns the launch's outputs
+        and the seconds those puts took."""
+        mesh = self._mesh_for_bucket(bucket)
+        sharding = (None if mesh is None
+                    else NamedSharding(mesh, _P(DD.mesh_axes(mesh))))
         attempt = 0
+        h2d_s = 0.0
         while True:
             try:
                 if self._faults is not None:
                     self._faults.maybe_fail("launch", route=route.name)
-                return prog.launch(*inputs)
+                with self.tracer.span("h2d", route=route.name) as sp:
+                    dev = sp.fence(jax.device_put(inputs, sharding))
+                h2d_s += sp.wall_s
+                return prog.launch(*dev), h2d_s
             except (ValueError, TypeError):
                 raise
             except Exception:
@@ -1703,9 +1731,18 @@ class FCMServeEngine:
         max_iters = int(self._route_cfg(route).max_iters)
         bad_pend: List[Any] = []
         bad_ids: set = set()
-        with self.tracer.span("bucket", route=route.name, bucket=bucket,
+        with self.tracer.span("bucket", annotate=("route", "bucket"),
+                              route=route.name, bucket=bucket,
                               n=len(chunk), fused=use_prog,
                               requests=[p.request_id for p in chunk]):
+            # Queue wait: each real lane's submit -> its batch's gather.
+            now = time.perf_counter()
+            waited = 0.0
+            for p in chunk:
+                sub = self._submit_t.get(p.request_id)
+                if sub is not None:
+                    waited += now - sub[0]
+            self._queue_wait(route.name).inc(waited)
             if use_prog:
                 # Device-resident fast path: host-side stacking, ONE
                 # jitted dispatch (ingest-binning + solve + defuzzify),
@@ -1717,8 +1754,9 @@ class FCMServeEngine:
                 try:
                     with self.tracer.span("launch",
                                           route=route.name) as sp_s:
-                        outs = sp_s.fence(
-                            self._launch_attempts(route, prog, inputs))
+                        outs, h2d_s = self._launch_attempts(
+                            route, prog, inputs, bucket)
+                        outs = sp_s.fence(outs)
                 except (ValueError, TypeError):
                     raise       # programming errors are not transient
                 except Exception:
@@ -1729,6 +1767,9 @@ class FCMServeEngine:
                     self._breaker_success(route.name)
                     with self.tracer.span("scatter",
                                           route=route.name) as sp_m:
+                        with self.tracer.span("d2h",
+                                              route=route.name) as sp_d:
+                            outs = jax.device_get(outs)
                         scattered = prog.scatter(self, chunk, outs)
                     res_list, centers, n_iters, total_iters = scattered[:4]
                     if len(scattered) > 4:      # telemetry-aware program
@@ -1746,12 +1787,15 @@ class FCMServeEngine:
                             continue
                         r.converged = bool(iters_np[lane] < max_iters)
                         self._finish(route, results, r)
-                    self._stage_seconds(route.name, "ingest").inc(
+                    self._stage_seconds(route.name, "gather").inc(
                         sp_g.wall_s)
                     self._stage_seconds(route.name, "solve").inc(
                         sp_s.wall_s)
+                    self._stage_seconds(route.name, "h2d").inc(h2d_s)
                     self._stage_seconds(route.name, "materialize").inc(
                         sp_m.wall_s)
+                    self._stage_seconds(route.name, "d2h").inc(
+                        sp_d.wall_s)
             if not use_prog:
                 with self.tracer.span("build", route=route.name) as sp_g:
                     problem, cfg = route.build_problem(self, chunk, bucket)
@@ -1797,7 +1841,7 @@ class FCMServeEngine:
                             if conv is not None:
                                 r.converged = bool(conv[lane])
                             self._finish(route, results, r)
-                self._stage_seconds(route.name, "ingest").inc(sp_g.wall_s)
+                self._stage_seconds(route.name, "gather").inc(sp_g.wall_s)
                 self._stage_seconds(route.name, "solve").inc(sp_s.wall_s)
                 self._stage_seconds(route.name, "materialize").inc(
                     sp_m.wall_s)
@@ -1934,11 +1978,14 @@ class FCMServeEngine:
         fit_s = s.get("fit_seconds", 0.0)
         s["images_per_sec"] = (s.get("batched_images", 0) / fit_s
                                if fit_s > 0 else 0.0)
-        # Per-route stage breakdown (ingest = submit validation + flush
-        # stacking, solve = the device dispatch, materialize = unpack /
-        # per-request labeling) — what overhead regressions page on.
+        # Per-route stage breakdown (ingest = submit-time validation,
+        # gather = flush-time batch stacking, solve = the fenced device
+        # dispatch, materialize = unpack / per-request labeling) — what
+        # overhead regressions page on.
         s["stage_seconds"] = {
             r.name: {"ingest": s[r.stat("ingest")],
+                     "gather": self._stage_seconds(r.name,
+                                                   "gather").snapshot(),
                      "solve": s[r.stat("seconds")],
                      "materialize": s[r.stat("materialize")]}
             for r in ROUTES.values()}

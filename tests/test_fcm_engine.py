@@ -546,12 +546,14 @@ def test_stage_seconds_breakdown_in_stats():
     s = eng.stats()["stage_seconds"]
     assert set(s) == set(eng.stats()["method_requests"])
     for route_stages in s.values():
-        assert set(route_stages) == {"ingest", "solve", "materialize"}
+        assert set(route_stages) == {"ingest", "gather", "solve",
+                                     "materialize"}
     img, _ = phantom.phantom_slice(32, 32, seed=0)
     eng.segment([img])
     eng.segment([img], method="spatial")
     s = eng.stats()["stage_seconds"]
     assert s["histogram"]["ingest"] >= 0 and s["histogram"]["solve"] > 0
+    assert s["histogram"]["gather"] > 0 and s["spatial"]["gather"] > 0
     assert s["spatial"]["solve"] > 0
 
 

@@ -237,22 +237,55 @@ def test_span_exception_marks_error_and_propagates():
 
 
 def test_disabled_tracer_times_but_records_nothing():
-    reg = obs.MetricsRegistry()
-    tr = obs.Tracer(enabled=False, metrics=reg)
+    tr = obs.Tracer(enabled=False)
     with tr.span("solve") as sp:
         pass
     assert sp.wall_s is not None             # timing still works
     assert tr.traces() == []
-    assert reg.snapshot()["histograms"] == {}
 
 
-def test_ring_false_skips_ring_but_feeds_metrics():
-    reg = obs.MetricsRegistry()
-    tr = obs.Tracer(metrics=reg)
-    with tr.span("ingest", ring=False):
-        pass
+def _profiled(fn, log_dir):
+    """Run ``fn`` under the JAX profiler; returns the host events named
+    ``fcm.*`` as (name, start_ns, end_ns, stats) in start order."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("fcm."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_ring_false_skips_ring_but_feeds_metrics(tmp_path):
+    """A ring=False span stays out of the ring but still reaches the
+    profiler trace as an ``fcm.<name>`` annotation (without attrs it was
+    not asked to carry)."""
+    tr = obs.Tracer()
+
+    def body():
+        with tr.span("ingest", ring=False, route="histogram") as sp:
+            pass
+        assert sp.wall_s is not None
+
+    events = _profiled(body, tmp_path)
     assert tr.traces() == []
-    assert reg.peek("span_seconds", span="ingest").count == 1
+    assert [(e[0], e[3]) for e in events] == [("fcm.ingest", {})]
 
 
 def test_ring_buffer_caps_at_max_traces():
@@ -272,6 +305,105 @@ def test_span_fence_records_device_time():
         out = sp.fence(jnp.arange(8) * 2)
     assert int(out[3]) == 6
     assert sp.device_s is not None and sp.device_s <= sp.wall_s
+
+
+# ---------------------------------------------------------------------------
+# Engine spans in the profiler, stage and queue-wait counters
+# ---------------------------------------------------------------------------
+
+def _engine(**kw):
+    from repro.serving.fcm_engine import FCMServeEngine
+    return FCMServeEngine(F.FCMConfig(max_iters=300), cache_size=0, **kw)
+
+
+def _slices(n, h=32, w=32):
+    return [phantom.phantom_slice(h, w, noise=2.0 + i, seed=i)[0]
+            for i in range(n)]
+
+
+def _stage(eng, route, stage):
+    return eng.metrics.counter("route.stage_seconds", route=route,
+                               stage=stage).value
+
+
+def test_flush_spans_nest_in_profiler_trace(tmp_path):
+    """One traced flush leaves fcm.flush > fcm.bucket > (fcm.gather,
+    fcm.launch > fcm.h2d, fcm.scatter > fcm.d2h) as host events, in
+    that order, with tracing off (the annotations do not need the
+    ring); route and bucket ride on fcm.bucket alone."""
+    eng = _engine(batch_sizes=(4,), tracing=False)
+    imgs = _slices(4)
+    eng.segment(imgs)                        # compile outside the trace
+
+    events = _profiled(lambda: eng.segment(imgs), tmp_path)
+    by = {}
+    for e in events:
+        by.setdefault(e[0], []).append(e)
+    assert sorted(by) == ["fcm.bucket", "fcm.d2h", "fcm.flush",
+                          "fcm.gather", "fcm.h2d", "fcm.ingest",
+                          "fcm.launch", "fcm.scatter"]
+    assert len(by["fcm.ingest"]) == 4
+    for name in set(by) - {"fcm.ingest"}:
+        assert len(by[name]) == 1, name
+    one = {k: v[0] for k, v in by.items()}
+
+    def inside(child, parent):
+        c, p = one[child], one[parent]
+        return p[1] <= c[1] and c[2] <= p[2]
+
+    assert inside("fcm.bucket", "fcm.flush")
+    for stage in ("fcm.gather", "fcm.launch", "fcm.scatter"):
+        assert inside(stage, "fcm.bucket")
+    assert inside("fcm.h2d", "fcm.launch")
+    assert inside("fcm.d2h", "fcm.scatter")
+    assert (one["fcm.gather"][2] <= one["fcm.launch"][1]
+            and one["fcm.launch"][2] <= one["fcm.scatter"][1])
+    assert one["fcm.bucket"][3] == {"route": "histogram", "bucket": 4}
+    for name, ev in one.items():
+        if name != "fcm.bucket":
+            assert ev[3] == {}, name         # no request ids, no attrs
+    assert eng.tracer.traces() == []
+
+
+def test_submit_without_flush_moves_ingest_not_gather():
+    eng = _engine(batch_sizes=(4,))
+    for img in _slices(3):
+        eng.submit(img)
+    assert _stage(eng, "histogram", "ingest") > 0
+    assert _stage(eng, "histogram", "gather") == 0
+    assert eng.stats()["stage_seconds"]["histogram"]["gather"] == 0
+    eng.flush()
+    assert _stage(eng, "histogram", "gather") > 0
+    assert eng.stats()["stage_seconds"]["histogram"]["gather"] > 0
+
+
+@pytest.mark.parametrize("route", ["histogram", "spatial"])
+def test_transfers_sit_inside_their_stages(route):
+    """h2d is a child of the fenced launch and d2h of the scatter, so
+    their counters never exceed solve and materialize."""
+    eng = _engine(batch_sizes=(4,))
+    eng.segment(_slices(3), method=route)
+    h2d, d2h = _stage(eng, route, "h2d"), _stage(eng, route, "d2h")
+    assert 0 < h2d <= _stage(eng, route, "solve")
+    assert 0 < d2h <= _stage(eng, route, "materialize")
+
+
+def test_queue_wait_counts_submit_to_gather():
+    """A planted 50 ms between submit and flush shows in every lane's
+    queue wait: route.queue_wait_seconds >= 0.05 x n."""
+    import time
+
+    eng = _engine(batch_sizes=(4,))
+    imgs = _slices(3)
+    eng.segment(imgs)                        # compile outside the wait
+    wait = eng.metrics.counter("route.queue_wait_seconds",
+                               route="histogram")
+    before = wait.value
+    for img in imgs:
+        eng.submit(img)
+    time.sleep(0.05)
+    eng.flush()
+    assert wait.value - before >= 0.05 * len(imgs)
 
 
 # ---------------------------------------------------------------------------

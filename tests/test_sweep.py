@@ -159,7 +159,7 @@ def test_serving_cell_record_roundtrips_schema():
     rec = _roundtrip(sweep._run_serving_cell(cell, tiny=True))
     assert rec["status"] == "ok"
     assert set(rec["metrics"]["stage_seconds"]) == {
-        "ingest", "solve", "materialize"}
+        "ingest", "gather", "solve", "materialize"}
 
 
 def test_solver_cell_telemetry_does_not_leak_to_default_registry():
