@@ -683,7 +683,12 @@ def _materialize_spatial_batch(eng, chunk, centers, n_iters):
 
 
 def _spatial_program_key(eng, chunk):
-    return ("sp",) + chunk[0].pixels.shape  # bucket_key groups by shape
+    # A chunk whose every lane is uint8 stages uint8 (a quarter of the
+    # float32 bytes the H2D would move); any other chunk stages float32.
+    # bucket_key groups by shape.
+    dtype = np.dtype(np.uint8 if all(q.pixels.dtype == np.uint8
+                                     for q in chunk) else np.float32)
+    return ("sp", dtype) + chunk[0].pixels.shape
 
 
 def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
@@ -694,7 +699,7 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
     reference stencil loop — either way the route sheds the
     per-stage host synchronization that made spatial serving the
     highest-overhead route."""
-    shape = key[1:]
+    dtype, shape = key[1], key[2:]
     scfg = eng.spatial_cfg
     c, m = scfg.n_clusters, float(scfg.m)
     alpha = float(scfg.alpha)
@@ -705,6 +710,10 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
                             n_rows=KR.stencil_pixels(shape), c=c).name
 
     def launch_fn(imgs):
+        # Widen on the chip: uint8 -> float32 is exact, so the solve sees
+        # the very array float32 staging would have sent (flat uint8
+        # lanes regain their grid shape; float32 lanes pass unchanged).
+        imgs = imgs.reshape(imgs.shape[:1] + shape).astype(jnp.float32)
         v, delta, iters, total = SV.stencil_batched_solve(
             imgs, c, m, alpha, neighbors, eps, max_iters, impl=impl)
         u = jax.vmap(lambda im, vv: SP.spatial_membership(
@@ -719,14 +728,16 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
         launch_fn, 1)
 
     def gather(eng_, chunk, bucket_):
-        imgs = np.empty((bucket_,) + shape, np.float32)
+        imgs = np.empty((bucket_,) + shape, dtype)
         for i, q in enumerate(chunk):
             imgs[i] = q.pixels
         # Padding lanes replay the first image (frozen-lane masking makes
         # them cost one lane of compute; dropped on output).
         for i in range(len(chunk), bucket_):
             imgs[i] = imgs[0]
-        return (imgs,)
+        # uint8 goes flat, (bucket, pixels), as the histogram route's
+        # pixels do: a 2-D array the put need not relayout per slice.
+        return (imgs.reshape(bucket_, -1) if dtype == np.uint8 else imgs,)
 
     def scatter(eng_, chunk, outs):
         v, delta, iters, total, labels = outs
@@ -1648,8 +1659,9 @@ class FCMServeEngine:
         attempt first puts the host ``inputs`` on the device with the
         launch's sharding (an ``h2d`` span, fenced: the batch axis split
         over the mesh ``_jit_launch`` shards this bucket over, so no
-        reshard runs inside the launch); returns the launch's outputs
-        and the seconds those puts took."""
+        reshard runs inside the launch, and ``route.h2d_bytes`` counts
+        the bytes each put moved); returns the launch's outputs and the
+        seconds those puts took."""
         mesh = self._mesh_for_bucket(bucket)
         sharding = (None if mesh is None
                     else NamedSharding(mesh, _P(DD.mesh_axes(mesh))))
@@ -1662,6 +1674,8 @@ class FCMServeEngine:
                 with self.tracer.span("h2d", route=route.name) as sp:
                     dev = sp.fence(jax.device_put(inputs, sharding))
                 h2d_s += sp.wall_s
+                self._route_counter("h2d_bytes", route.name).inc(
+                    sum(x.nbytes for x in inputs))
                 return prog.launch(*dev), h2d_s
             except (ValueError, TypeError):
                 raise

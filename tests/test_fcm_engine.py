@@ -482,6 +482,68 @@ def test_fused_spatial_program_matches_staged_route_path():
         assert (f.labels == s.labels).all()
 
 
+def _noisy_slices(n=3, h=40, w=48):
+    return [phantom.noisy_phantom_slice(h, w, noise=8.0 + 3 * i,
+                                        impulse=0.04, seed=i)[0]
+            for i in range(n)]
+
+
+def test_spatial_gather_stages_uint8_chunks_flat():
+    """A chunk whose every lane is uint8 stages ONE flat uint8 array of
+    bucket x pixels bytes; padding lanes replay lane 0."""
+    from repro.serving import fcm_engine as E
+
+    imgs = _noisy_slices()
+    eng = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
+    chunk = [E._PendingSpatial(i, img) for i, img in enumerate(imgs)]
+    prog = eng._program_for(E.ROUTES["spatial"], chunk, 4)
+    (staged,) = prog.gather(eng, chunk, 4)
+    assert staged.dtype == np.uint8 and staged.shape == (4, 40 * 48)
+    assert staged.nbytes == 4 * 40 * 48
+    for lane, img in enumerate(imgs + imgs[:1]):
+        assert (staged[lane] == img.reshape(-1)).all()
+
+
+@pytest.mark.parametrize("kinds,staged", [
+    ((np.uint8,), np.uint8),
+    ((np.float32,), np.float32),
+    ((np.uint16,), np.float32),
+    ((np.uint8, np.float32), np.float32)],
+    ids=["uint8", "float32", "uint16", "mixed"])
+def test_spatial_staging_dtype_serves_what_float32_staging_serves(
+        kinds, staged):
+    """A spatial chunk stages uint8 only when every lane is uint8, and
+    widens on the device: uint8 -> float32 is exact, so the served
+    centers, iterations and labels are bit-identical to the same slices
+    submitted as float32, and every dtype still matches the direct
+    FCM_S fit. ``route.h2d_bytes{spatial}`` grows by exactly the staged
+    bytes of each batch (3 lanes in a bucket of 4: padding included)."""
+    base = _noisy_slices()
+    imgs = [img.astype(kinds[i % len(kinds)]) for i, img in enumerate(base)]
+    eng = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
+    h2d = eng.metrics.counter("route.h2d_bytes", route="spatial")
+    per_batch = 4 * 40 * 48 * np.dtype(staged).itemsize
+    got = eng.segment(imgs, method="spatial")
+    assert h2d.value == per_batch
+    again = eng.segment(imgs, method="spatial")
+    assert h2d.value == 2 * per_batch
+    ref = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0).segment(
+        [img.astype(np.float32) for img in base], method="spatial")
+    for a, b, c in zip(got, again, ref):
+        for r in (a, b):
+            assert (r.centers == c.centers).all()
+            assert r.n_iters == c.n_iters
+            assert (r.labels == c.labels).all()
+    for img, r in zip(base, got):
+        solo = SV.solve(SV.spatial_problem(img.astype(np.float32),
+                                           eng.spatial_cfg),
+                        eng.spatial_cfg)
+        np.testing.assert_allclose(r.centers, np.asarray(solo.centers),
+                                   atol=1e-5)
+        assert (r.labels == np.asarray(solo.labels)).all()
+        assert r.n_iters == solo.n_iters
+
+
 def test_program_cache_reused_across_flushes_and_engines():
     imgs = [phantom.phantom_slice(32, 32, noise=2.0 + i, seed=i)[0]
             for i in range(3)]
