@@ -854,7 +854,10 @@ class FCMServeEngine:
     batch axis sharded over the mesh (``core/distributed.shard_map``);
     program caches key on the mesh generation so ``set_mesh`` can never
     serve a stale single-device (or other-mesh) executable. A one-device
-    mesh (or ``mesh=None``) runs the exact single-device path.
+    mesh (or ``mesh=None``) runs the exact single-device path. Each
+    sharded launch counts ``route.sharded_batches`` and its shard
+    balance (``route.shard_iters_sum`` / ``route.shard_iters_max``), and
+    the ``fcm.bucket`` annotation carries ``shards``.
     """
 
     def __init__(self, cfg: F.FCMConfig = F.FCMConfig(),
@@ -1686,6 +1689,22 @@ class FCMServeEngine:
                 time.sleep(self.retry_backoff_s * (2 ** attempt))
                 attempt += 1
 
+    def _count_shards(self, route_name: str, iters: np.ndarray,
+                      shards: int) -> None:
+        """Shard balance of one sharded launch, from the per-lane
+        iterations it returned: each shard runs its contiguous
+        ``bucket / shards`` lanes (padding lanes included) one after
+        another, so the shard with the most lane iterations sets the
+        launch's time. ``route.shard_iters_sum`` adds every shard's lane
+        iterations and ``route.shard_iters_max`` ``shards`` times the
+        largest shard's: their ratio is the balance (1.0 is even)."""
+        per = np.asarray(iters, np.int64).reshape(shards, -1).sum(axis=1)
+        self._route_counter("sharded_batches", route_name).inc()
+        self._route_counter("shard_iters_sum", route_name).inc(
+            int(per.sum()))
+        self._route_counter("shard_iters_max", route_name).inc(
+            shards * int(per.max()))
+
     def _route_cfg(self, route: RouteSpec):
         """The config whose eps/max_iters govern this route's fits."""
         if route.name == "spatial":
@@ -1745,9 +1764,12 @@ class FCMServeEngine:
         max_iters = int(self._route_cfg(route).max_iters)
         bad_pend: List[Any] = []
         bad_ids: set = set()
-        with self.tracer.span("bucket", annotate=("route", "bucket"),
+        mesh = self._mesh_for_bucket(bucket) if use_prog else None
+        shards = 1 if mesh is None else mesh.size
+        with self.tracer.span("bucket",
+                              annotate=("route", "bucket", "shards"),
                               route=route.name, bucket=bucket,
-                              n=len(chunk), fused=use_prog,
+                              shards=shards, n=len(chunk), fused=use_prog,
                               requests=[p.request_id for p in chunk]):
             # Queue wait: each real lane's submit -> its batch's gather.
             now = time.perf_counter()
@@ -1794,6 +1816,8 @@ class FCMServeEngine:
                     finite = np.isfinite(
                         centers.reshape(centers.shape[0], -1)).all(axis=1)
                     iters_np = np.asarray(n_iters)
+                    if shards > 1:
+                        self._count_shards(route.name, iters_np, shards)
                     for lane, (p, r) in enumerate(zip(chunk, res_list)):
                         if not bool(finite[lane]):
                             bad_pend.append(p)

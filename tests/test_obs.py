@@ -330,7 +330,8 @@ def test_flush_spans_nest_in_profiler_trace(tmp_path):
     """One traced flush leaves fcm.flush > fcm.bucket > (fcm.gather,
     fcm.launch > fcm.h2d, fcm.scatter > fcm.d2h) as host events, in
     that order, with tracing off (the annotations do not need the
-    ring); route and bucket ride on fcm.bucket alone."""
+    ring); route, bucket and shards (1: no mesh) ride on fcm.bucket
+    alone."""
     eng = _engine(batch_sizes=(4,), tracing=False)
     imgs = _slices(4)
     eng.segment(imgs)                        # compile outside the trace
@@ -358,7 +359,8 @@ def test_flush_spans_nest_in_profiler_trace(tmp_path):
     assert inside("fcm.d2h", "fcm.scatter")
     assert (one["fcm.gather"][2] <= one["fcm.launch"][1]
             and one["fcm.launch"][2] <= one["fcm.scatter"][1])
-    assert one["fcm.bucket"][3] == {"route": "histogram", "bucket": 4}
+    assert one["fcm.bucket"][3] == {"route": "histogram", "bucket": 4,
+                                    "shards": 1}
     for name, ev in one.items():
         if name != "fcm.bucket":
             assert ev[3] == {}, name         # no request ids, no attrs
