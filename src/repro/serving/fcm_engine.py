@@ -84,7 +84,10 @@ from .admission import (DeadlineExceeded, EngineShutdown, InvalidInput,
 class SegmentationResult:
     """Per-request output."""
     request_id: int
-    labels: np.ndarray            # same spatial shape as the submitted image
+    #: Same spatial shape as the submitted image. The spatial route's
+    #: are uint8 for c <= 256 (int32 above): a quarter of the bytes the
+    #: chip sends back; the other routes' are int32.
+    labels: np.ndarray
     centers: np.ndarray           # (c,) scalar or (c, D) vector features
     n_iters: int                  # 0 for cache hits
     cache_hit: bool
@@ -654,6 +657,13 @@ def _build_spatial(eng, chunk, bucket):
                              cfg=scfg), scfg
 
 
+def _spatial_label_dtype(n_clusters: int) -> np.dtype:
+    """The dtype of the spatial route's label maps: uint8 holds every
+    label 0..c-1 exactly for c <= 256, and moves a quarter of int32's
+    bytes from the chip; int32 above that."""
+    return np.dtype(np.uint8 if n_clusters <= 256 else np.int32)
+
+
 def _materialize_spatial(eng, q, centers, n_iters, cache_hit):
     # Single-request face of the batch materializer (the route registers
     # materialize_batch, so flush() normally never calls this; it exists
@@ -675,7 +685,8 @@ def _materialize_spatial_batch(eng, chunk, centers, n_iters):
     u = jax.vmap(lambda im, v: SP.spatial_membership(
         im, v, scfg.m, scfg.alpha, neighbors))(
             imgs, jnp.asarray(centers[:len(chunk)]))
-    labels = np.asarray(jnp.argmax(u, axis=1).astype(jnp.int32))
+    labels = np.asarray(jnp.argmax(u, axis=1).astype(
+        _spatial_label_dtype(scfg.n_clusters)))
     return [SegmentationResult(q.request_id, labels[i],
                                np.asarray(centers[i]), int(n_iters[i]),
                                False, method="spatial")
@@ -705,6 +716,7 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
     alpha = float(scfg.alpha)
     neighbors = _spatial_neighbors(eng, len(shape))
     eps, max_iters = float(scfg.eps), int(scfg.max_iters)
+    label_dtype = _spatial_label_dtype(c)
     platform = jax.default_backend()
     impl = kops.select_step("stencil", platform=platform, batched=True,
                             n_rows=KR.stencil_pixels(shape), c=c).name
@@ -718,8 +730,11 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
             imgs, c, m, alpha, neighbors, eps, max_iters, impl=impl)
         u = jax.vmap(lambda im, vv: SP.spatial_membership(
             im, vv, m, alpha, neighbors))(imgs, v)
-        labels = jnp.argmax(u, axis=1).astype(jnp.int32)
-        return v, delta, iters, total, labels
+        # Narrow on the chip, and flat (bucket, pixels): the D2H then
+        # moves uint8 rows, where a tiled (bucket, H, W) map pads and
+        # fetches slower, most of all from several devices.
+        labels = jnp.argmax(u, axis=1).astype(label_dtype)
+        return v, delta, iters, total, labels.reshape(labels.shape[0], -1)
 
     launch = _jit_launch(
         eng, bucket,
@@ -743,7 +758,7 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
         v, delta, iters, total, labels = outs
         centers = np.asarray(v)
         iters_np = np.asarray(iters)
-        labels_np = np.asarray(labels)
+        labels_np = np.asarray(labels).reshape((-1,) + shape)
         res = [SegmentationResult(q.request_id, labels_np[i], centers[i],
                                   int(iters_np[i]), False,
                                   method="spatial")
@@ -1834,6 +1849,8 @@ class FCMServeEngine:
                         sp_m.wall_s)
                     self._stage_seconds(route.name, "d2h").inc(
                         sp_d.wall_s)
+                    self._route_counter("d2h_bytes", route.name).inc(
+                        sum(x.nbytes for x in jax.tree_util.tree_leaves(outs)))
             if not use_prog:
                 with self.tracer.span("build", route=route.name) as sp_g:
                     problem, cfg = route.build_problem(self, chunk, bucket)

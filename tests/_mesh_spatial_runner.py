@@ -1,10 +1,10 @@
 """Subprocess entry for the four-chip spatial deployment's test: on 4
 fake host devices, an engine whose spatial launches shard over a
-``data`` mesh serves noisy uint8 phantom slices at buckets 1, 4 and 8.
-Its answers must agree with a plain float32 FCM_S written here, be
-bitwise those of a one-device engine, and its shard counters must match
-a hand count of the lanes each shard ran. Prints MESH_SPATIAL_OK on
-success."""
+``data`` mesh serves noisy uint8 phantom slices at buckets 1, 4, 8, 16
+and 64. Its answers must agree with a plain float32 FCM_S written here,
+be bitwise those of a one-device engine, labels uint8 from both, and
+its shard counters must match a hand count of the lanes each shard ran.
+Prints MESH_SPATIAL_OK on success."""
 import os
 import sys
 
@@ -120,7 +120,7 @@ def engine(mesh=None):
     cfg = SpatialFCMConfig(n_clusters=C, m=M, eps=EPS, max_iters=MAX_ITERS,
                            alpha=ALPHA, neighbors=8)
     return FCMServeEngine(F.FCMConfig(n_clusters=C, max_iters=MAX_ITERS),
-                          batch_sizes=(1, 4, 8), spatial_cfg=cfg,
+                          batch_sizes=(1, 4, 8, 16, 64), spatial_cfg=cfg,
                           cache_size=0, mesh=mesh)
 
 
@@ -143,8 +143,12 @@ def main():
     # One slow lane: uniform noise has no clusters to settle into and
     # takes about half as many iterations again as a phantom slice.
     slow = np.random.default_rng(3).integers(0, 256, (H, W)).astype(np.uint8)
-    batches = [slices[:1], slices[1:4], slices[:5] + [slow] + slices[5:7]]
-    for batch, bucket in zip(batches, (1, 4, 8)):
+    more = [phantom.noisy_phantom_slice(H, W, slice_pos=0.3 + 0.01 * i,
+                                        noise=15.0, impulse=0.05,
+                                        seed=100 + i)[0] for i in range(40)]
+    batches = [slices[:1], slices[1:4], slices[:5] + [slow] + slices[5:7],
+               more[:12], more]
+    for batch, bucket in zip(batches, (1, 4, 8, 16, 64)):
         before = counters(eng)
         notes.seen.clear()
         got = eng.segment(batch, method="spatial")
@@ -157,6 +161,7 @@ def main():
                                   np.asarray(s.centers)), bucket
             assert r.n_iters == s.n_iters, bucket
             assert np.array_equal(r.labels, s.labels), bucket
+            assert r.labels.dtype == s.labels.dtype == np.uint8, bucket
         shards = 1 if bucket == 1 else 4
         assert seen == [(bucket, shards)], seen
         if bucket == 1:
@@ -170,7 +175,7 @@ def main():
         if bucket == 8:
             assert got[5].n_iters > max(r.n_iters for r in got[:5]), iters
             assert delta[1] < delta[2], delta
-    assert counters(eng)[0] == 2
+    assert counters(eng)[0] == 4
     assert counters(one) == [0, 0, 0]
     print("MESH_SPATIAL_OK")
 

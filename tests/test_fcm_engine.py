@@ -517,23 +517,44 @@ def test_spatial_staging_dtype_serves_what_float32_staging_serves(
     centers, iterations and labels are bit-identical to the same slices
     submitted as float32, and every dtype still matches the direct
     FCM_S fit. ``route.h2d_bytes{spatial}`` grows by exactly the staged
-    bytes of each batch (3 lanes in a bucket of 4: padding included)."""
+    bytes of each batch (3 lanes in a bucket of 4: padding included).
+    Labels come back as uint8 (c = 4), from the fused program and from
+    the staged path alike, with the same values; ``route.d2h_bytes``
+    grows by exactly the fetched outputs' bytes, the labels' a byte a
+    pixel of every lane."""
+    from repro.serving import fcm_engine as E
+
     base = _noisy_slices()
     imgs = [img.astype(kinds[i % len(kinds)]) for i, img in enumerate(base)]
     eng = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
     h2d = eng.metrics.counter("route.h2d_bytes", route="spatial")
+    d2h = eng.metrics.counter("route.d2h_bytes", route="spatial")
     per_batch = 4 * 40 * 48 * np.dtype(staged).itemsize
+    # centers (4, c) f32, delta (4,) f32, iters (4,) s32, total s32, labels
+    fetched = 4 * 4 * 4 + 4 * 4 + 4 * 4 + 4 + 4 * 40 * 48
     got = eng.segment(imgs, method="spatial")
-    assert h2d.value == per_batch
+    assert h2d.value == per_batch and d2h.value == fetched
     again = eng.segment(imgs, method="spatial")
-    assert h2d.value == 2 * per_batch
+    assert h2d.value == 2 * per_batch and d2h.value == 2 * fetched
     ref = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0).segment(
         [img.astype(np.float32) for img in base], method="spatial")
-    for a, b, c in zip(got, again, ref):
+    spatial = E.ROUTES["spatial"]
+    E.register_route(dataclasses.replace(spatial, program_key=None,
+                                         make_program=None))
+    try:
+        slow = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
+        res_staged = slow.segment(imgs, method="spatial")
+        assert slow.stats()["compiled_programs"] == 0
+    finally:
+        E.register_route(spatial)
+    for a, b, c, s in zip(got, again, ref, res_staged):
         for r in (a, b):
             assert (r.centers == c.centers).all()
             assert r.n_iters == c.n_iters
             assert (r.labels == c.labels).all()
+        assert a.labels.dtype == s.labels.dtype == np.uint8
+        assert a.labels.shape == s.labels.shape == (40, 48)
+        assert (a.labels == s.labels).all()
     for img, r in zip(base, got):
         solo = SV.solve(SV.spatial_problem(img.astype(np.float32),
                                            eng.spatial_cfg),
@@ -542,6 +563,17 @@ def test_spatial_staging_dtype_serves_what_float32_staging_serves(
                                    atol=1e-5)
         assert (r.labels == np.asarray(solo.labels)).all()
         assert r.n_iters == solo.n_iters
+
+
+@pytest.mark.parametrize("c,dtype", [
+    (2, np.uint8), (4, np.uint8), (256, np.uint8), (257, np.int32)])
+def test_spatial_label_dtype_is_the_narrowest_that_holds_c(c, dtype):
+    """uint8 holds labels 0..255, so c <= 256 clusters label in uint8;
+    a 257th cluster needs int32."""
+    from repro.serving import fcm_engine as E
+
+    assert E._spatial_label_dtype(c) == np.dtype(dtype)
+    assert np.iinfo(E._spatial_label_dtype(c)).max >= c - 1
 
 
 def test_program_cache_reused_across_flushes_and_engines():

@@ -37,10 +37,11 @@ def test_mesh_h2d_puts_inputs_with_the_launch_sharding():
 
 def test_mesh_spatial_matches_reference_and_counts_shards():
     """On 4 virtual devices the spatial route of a meshed engine serves
-    noisy uint8 slices at buckets 1, 4 and 8 as a plain float32 FCM_S
-    does, bitwise as a one-device engine does, and its shard counters
-    count buckets 4 and 8 only, matching a hand count of each shard's
-    lane iterations on a batch with one slow lane."""
+    noisy uint8 slices at buckets 1, 4, 8, 16 and 64 as a plain float32
+    FCM_S does, bitwise as a one-device engine does (uint8 labels from
+    both), and its shard counters count the sharded buckets only,
+    matching a hand count of each shard's lane iterations on a batch
+    with one slow lane."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
