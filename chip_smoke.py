@@ -315,13 +315,10 @@ def phase_serving(job, platform: str):
     check(not counters(reg, "solver.salvaged_lanes"),
           f"salvaged lanes {counters(reg, 'solver.salvaged_lanes')}")
     got = {r: set(v) for r, v in eng.stats()["route_impls"].items()}
-    # The superpixel route compresses on ingest and solves through
-    # solve_batched; both record the impl they ran.
-    got["superpixel"] = (
-        {f"slic_assign/{lab['impl']}"
-         for lab, _ in counters(reg, "slic.fits")}
-        | {f"{lab['kind']}/{lab['impl']}"
-           for lab, _ in counters(reg, "solver.solves")})
+    # The superpixel route compresses on ingest, which records the SLIC
+    # impl it ran; its program names the solve's.
+    got["superpixel"] = got.get("superpixel", set()) | {
+        f"slic_assign/{lab['impl']}" for lab, _ in counters(reg, "slic.fits")}
     for route in routes:
         log(f"   {route} resolved {sorted(got.get(route, ()))}, "
             f"registry picks {sorted(want[route])} on {platform}")

@@ -4,18 +4,15 @@ The LM :class:`~repro.serving.engine.ServeEngine` amortizes device
 launches across a token batch; this engine does the same across *images*.
 Every serving method is a declarative :class:`RouteSpec` in a route
 registry — an ingest transform (validate / compress), a bucket key
-(requests sharing one may share one device launch), a problem builder
-(payloads -> one batched :class:`repro.core.solver.FCMProblem`), a
-materializer (per-request labels from fitted centers), and a cache
-policy. ``flush`` is route-agnostic: group by bucket key, pad to a
-fixed batch size, run ONE :func:`repro.core.solver.solve_batched` per
-bucket. Adding an FCM variant to serving = registering a RouteSpec, not
-hand-routing a new queue.
+(requests sharing one may share one device launch), a
+:class:`RouteProgram` factory, and a cache policy. ``flush`` is
+route-agnostic: group by bucket key, pad to a fixed batch size, run ONE
+route-program launch per bucket. Adding an FCM variant to serving =
+registering a RouteSpec and its program, not hand-routing a new queue.
 
-Because every route builds a solver problem, *all four* methods batch
-across concurrent requests — including ``spatial`` (same-shape FCM_S
-grids stack into one per-lane-masked stencil loop) and ``superpixel``
-((K, D) payload groups), which previously ran one fit per request.
+*All four* methods batch across concurrent requests — including
+``spatial`` (same-shape FCM_S grids stack into one per-lane-masked
+stencil loop) and ``superpixel`` ((K, D) payload groups).
 Two batching tricks keep XLA recompilation off the steady-state path:
 
 * **Bucketing** — queued requests are padded up to the nearest size in
@@ -33,16 +30,17 @@ Two batching tricks keep XLA recompilation off the steady-state path:
   depend on pixel positions and vector features have no 256-bin key.
 
 **Device-resident route programs** (the serving face of the paper's
-"never leave the device" lesson): the hot routes additionally register a
+"never leave the device" lesson): every route registers a
 :class:`RouteProgram` — one *jitted* ingest->solve->defuzzify pipeline
 per (route, bucket, payload-shape), cached and reused across flushes —
-so a drained bucket is ONE device dispatch instead of four
-host-synchronized stages (host binning, bucket assembly, batched solve,
-per-request label dispatches). On TPU the program's stages are the
-Pallas binning / VMEM-resident whole-solve / fused defuzzify kernels;
-off-TPU the binning runs as host numpy (XLA CPU has no fast scatter)
-and the solve as the vmapped reference loop, still fused into one
-dispatch. Re-registering a route bumps its generation and evicts its
+so a drained bucket is ONE device dispatch. On TPU the program's stages
+are the Pallas binning / VMEM-resident whole-solve / fused defuzzify
+kernels; off-TPU the binning runs as host numpy (XLA CPU has no fast
+scatter) and the solve as the vmapped reference loop, still fused into
+one dispatch. The same factory builds each route's *reference* program
+(the registry's ``reference`` impls, same gather and scatter), which
+serves chunks the circuit breaker degrades and lanes the salvage
+re-solves. Re-registering a route bumps its generation and evicts its
 compiled programs, so a replaced spec can never serve a stale pipeline.
 
 Results are hard labels per request (same spatial shape as the input
@@ -71,7 +69,6 @@ from repro.core import distributed as DD
 from repro.core import fcm as F
 from repro.core import solver as SV
 from repro.core import spatial as SP
-from repro.core.batched import hist_rows
 from repro.kernels import fcm_resident as KR
 from repro.kernels import ops as kops
 from repro.superpixel import pipeline as SX
@@ -171,40 +168,27 @@ class RouteSpec:
     ``ingest(engine, img, rid)`` validates and reduces the payload (it
     must raise before consuming a request id on bad input);
     ``bucket_key(engine, payload)`` decides which payloads may share one
-    batched solve; ``build_problem(engine, chunk, bucket)`` stacks a
-    chunk (plus padding lanes up to ``bucket``) into one batched
-    :class:`~repro.core.solver.FCMProblem` and names the config whose
-    eps/max_iters govern the fit; ``materialize`` turns one lane's
-    fitted centers back into per-pixel labels. ``cacheable`` routes
-    carry a ``.key``/``.hist`` payload and go through the histogram LRU
-    + intra-flush dedup.
+    batched launch; ``program_key(engine, chunk)`` names the
+    compiled-program shape a drained chunk shares, and
+    ``make_program(engine, key, bucket, reference=False)`` builds the
+    :class:`RouteProgram` that turns the chunk (padded to ``bucket``)
+    into answers, compiled once per (route generation, bucket, key) and
+    cached on the engine; ``reference=True`` builds the same program on
+    the registry's reference impls. ``cacheable`` routes carry a
+    ``.key``/``.hist`` payload, go through the histogram LRU +
+    intra-flush dedup, and answer those hits from fitted centers with
+    ``materialize(engine, payload, centers, n_iters, cache_hit)``.
     """
     name: str
     ingest: Callable[["FCMServeEngine", np.ndarray, int], Any]
     bucket_key: Callable[["FCMServeEngine", Any], Hashable]
-    build_problem: Callable[["FCMServeEngine", List[Any], int],
-                            Tuple[SV.FCMProblem, F.FCMConfig]]
-    materialize: Callable[["FCMServeEngine", Any, np.ndarray, int, bool],
-                          SegmentationResult]
-    #: optional vmapped materializer for a whole fitted chunk — one
-    #: device launch instead of len(chunk); (engine, chunk, centers,
-    #: n_iters) -> results. Routes whose per-request labeling is itself
-    #: stencil-heavy (spatial) need this to keep the served throughput
-    #: at the batched-fit level.
-    materialize_batch: Optional[
-        Callable[["FCMServeEngine", List[Any], np.ndarray, np.ndarray],
-                 List[SegmentationResult]]] = None
+    program_key: Callable[["FCMServeEngine", List[Any]], Hashable]
+    make_program: Callable[..., "RouteProgram"]
+    materialize: Optional[
+        Callable[["FCMServeEngine", Any, np.ndarray, int, bool],
+                 SegmentationResult]] = None
     cacheable: bool = False
     stats_prefix: str = ""        # "" keeps the legacy histogram names
-    #: device-resident fast path: ``program_key(engine, chunk)`` names
-    #: the compiled-program shape a drained chunk can share (None =
-    #: this chunk has no fused program) and ``make_program(engine, key,
-    #: bucket)`` builds the :class:`RouteProgram` compiled once per
-    #: (route generation, bucket, key) and cached on the engine.
-    program_key: Optional[
-        Callable[["FCMServeEngine", List[Any]], Optional[Hashable]]] = None
-    make_program: Optional[
-        Callable[["FCMServeEngine", Hashable, int], "RouteProgram"]] = None
 
     def stat(self, name: str) -> str:
         if not self.stats_prefix:   # the histogram route predates routes
@@ -232,10 +216,9 @@ class RouteProgram:
     ingest-binning, the batched solve and defuzzification;
     ``scatter(engine, chunk, outputs)`` unpacks the device outputs into
     per-request results and returns ``(results, centers (B, ...),
-    n_iters (B,), total_iters[, final_delta (B,)])`` so flush-side
-    stats, convergence telemetry and the LRU cache see exactly what the
-    staged path would have produced (the trailing per-lane residual is
-    optional: pre-telemetry programs returning 4-tuples still run).
+    n_iters (B,), total_iters, final_delta (B,))``, which flush-side
+    stats, convergence telemetry and the LRU cache read alike for every
+    route.
     ``impls`` names the registry kernels the launch resolved
     (``"kind/impl"``), reported by ``stats()["route_impls"]``.
     """
@@ -243,7 +226,7 @@ class RouteProgram:
     launch: Callable[..., Tuple]
     scatter: Callable[["FCMServeEngine", List[Any], Tuple],
                       Tuple[List[SegmentationResult], np.ndarray,
-                            np.ndarray, int]]
+                            np.ndarray, int, np.ndarray]]
     impls: Tuple[str, ...] = ()
 
 
@@ -327,11 +310,21 @@ def _jit_launch(eng: "FCMServeEngine", bucket: int, cache_key: Hashable,
         full_key, lambda: jax.jit(_shard_launch(mesh, launch_fn, n_in)))
 
 
+def _impl(kind: str, reference: bool, **shape) -> str:
+    """The registry impl a route program launches for ``kind``: the
+    platform's pick for this shape, or ``reference`` when building the
+    reference program that degraded and salvaged chunks run."""
+    if reference:
+        return "reference"
+    return kops.select_step(kind, platform=jax.default_backend(),
+                            **shape).name
+
+
 ROUTES: "collections.OrderedDict[str, RouteSpec]" = collections.OrderedDict()
 
 #: The stages of ``route.stage_seconds{route,stage}``: ``ingest``
 #: (submit-time validation and reduction), ``gather`` (flush-time
-#: stacking of a batch: a program's gather or the staged path's build),
+#: stacking of a batch by its program's gather, fast or reference),
 #: ``solve`` (the fenced launch) and inside it ``h2d`` (the inputs put on
 #: the device), ``materialize`` (unpacking results) and inside it ``d2h``
 #: (the outputs fetched to the host), ``compress`` (superpixel ingest).
@@ -384,17 +377,6 @@ def _ensure_hist(eng: "FCMServeEngine", p: _Pending) -> _Pending:
     return p
 
 
-def _build_histogram(eng, chunk, bucket):
-    hists = np.stack([_ensure_hist(eng, p).hist for p in chunk])
-    n_pad = bucket - len(chunk)
-    if n_pad:
-        # Uniform-histogram padding lanes converge fast and are dropped.
-        pad = np.ones((n_pad, eng.n_bins), np.float32)
-        hists = np.concatenate([hists, pad])
-    hists = jnp.asarray(hists)
-    return SV.batch_problems(hist_rows(hists), hists, cfg=eng.cfg), eng.cfg
-
-
 def _label_lut(centers: np.ndarray, n_bins: int) -> np.ndarray:
     """n_bins-entry defuzzify LUT in plain numpy — identical f32
     arithmetic and tie-breaking to labels_from_centers, without a device
@@ -419,14 +401,14 @@ def _histogram_program_key(eng, chunk):
     return ("px", sizes.pop()) if len(sizes) == 1 else ("hist",)
 
 
-def _make_histogram_program(eng, key, bucket) -> RouteProgram:
+def _make_histogram_program(eng, key, bucket,
+                            reference=False) -> RouteProgram:
     cfg = eng.cfg
     c, m = cfg.n_clusters, float(cfg.m)
     eps, max_iters = float(cfg.eps), int(cfg.max_iters)
     nb = eng.n_bins
     platform = jax.default_backend()
-    impl = kops.select_step("flat", platform=platform, n_feat=1,
-                            batched=True, n_rows=nb, c=c).name
+    impl = _impl("flat", reference, n_feat=1, batched=True, n_rows=nb, c=c)
     vals = jnp.arange(nb, dtype=jnp.float32)
 
     def _solve_lut(hists):
@@ -441,17 +423,20 @@ def _make_histogram_program(eng, key, bucket) -> RouteProgram:
         return v2, delta, iters, total, lut
 
     def _gather_hists(eng_, chunk):
+        # Padding lanes are all-ones histograms, dropped on output.
         hists = np.ones((bucket, nb), np.float32)
         for i, p in enumerate(chunk):
             hists[i] = _ensure_hist(eng_, p).hist
         return hists
 
+    # The reference program keys apart: on the TPU its px launch takes
+    # the host histograms where the fast one bins on-chip.
     cache_key = ("histogram", platform, bucket, key, nb, c, m, eps,
-                 max_iters, impl)
+                 max_iters, impl) + (("reference",) if reference else ())
 
     if key[0] == "px":
         n = key[1]
-        on_tpu = platform == "tpu"
+        on_tpu = platform == "tpu" and not reference
         if on_tpu:
             def launch_fn(px):
                 # Ingest binning on-chip: the Pallas one-pass kernel.
@@ -545,33 +530,11 @@ def _pixel_rows(img: np.ndarray) -> np.ndarray:
             else imgf.reshape(-1))
 
 
-def _build_pixel(eng, chunk, bucket):
-    xs = np.stack([_pixel_rows(q.pixels) for q in chunk])
-    n_pad = bucket - len(chunk)
-    if n_pad:
-        # Padding lanes replay the first image; frozen-lane masking makes
-        # them cost one lane of compute, dropped on output.
-        xs = np.concatenate([xs, np.repeat(xs[:1], n_pad, axis=0)])
-    return SV.batch_problems(jnp.asarray(xs), cfg=eng.cfg), eng.cfg
-
-
-def _materialize_pixel(eng, q, centers, n_iters, cache_hit):
-    img = q.pixels
-    spatial_shape = img.shape[:-1] if img.ndim == 3 else img.shape
-    # Fused argmin labels: the (c, N) distance/membership matrix is
-    # never materialized (Pallas kernel on TPU, reference elsewhere).
-    labels = np.asarray(kops.defuzzify_labels(
-        jnp.asarray(_pixel_rows(img)),
-        jnp.asarray(centers))).reshape(spatial_shape)
-    return SegmentationResult(q.request_id, labels, np.asarray(centers),
-                              n_iters, cache_hit, method="pixel")
-
-
 def _pixel_program_key(eng, chunk):
     return ("px",) + chunk[0].pixels.shape  # bucket_key groups by shape
 
 
-def _make_pixel_program(eng, key, bucket) -> RouteProgram:
+def _make_pixel_program(eng, key, bucket, reference=False) -> RouteProgram:
     shape = key[1:]
     scalar = len(shape) == 2
     d = 1 if scalar else shape[-1]
@@ -580,10 +543,8 @@ def _make_pixel_program(eng, key, bucket) -> RouteProgram:
     c, m = cfg.n_clusters, float(cfg.m)
     eps, max_iters = float(cfg.eps), int(cfg.max_iters)
     platform = jax.default_backend()
-    impl = kops.select_step("flat", platform=platform, n_feat=d,
-                            batched=True, n_rows=n, c=c).name
-    labels_impl = kops.select_step("labels", platform=platform,
-                                   n_feat=d).name
+    impl = _impl("flat", reference, n_feat=d, batched=True, n_rows=n, c=c)
+    labels_impl = _impl("labels", reference, n_feat=d)
 
     def launch_fn(xs):
         w = jnp.ones(xs.shape[:2], jnp.float32)
@@ -609,8 +570,8 @@ def _make_pixel_program(eng, key, bucket) -> RouteProgram:
                       np.float32)
         for i, q in enumerate(chunk):
             xs[i] = _pixel_rows(q.pixels)
-        # Padding lanes replay the first image (frozen-lane masking makes
-        # them cost one lane of compute; dropped on output).
+        # Padding lanes replay the first image: each runs lane 0's whole
+        # solve, and its answer is dropped.
         for i in range(len(chunk), bucket_):
             xs[i] = xs[0]
         return (xs,)
@@ -644,53 +605,11 @@ def _spatial_neighbors(eng, ndim: int) -> int:
     return eng.spatial_cfg.neighbors if ndim == 2 else 6
 
 
-def _build_spatial(eng, chunk, bucket):
-    imgs = np.stack([q.pixels.astype(np.float32) for q in chunk])
-    n_pad = bucket - len(chunk)
-    if n_pad:
-        imgs = np.concatenate([imgs, np.repeat(imgs[:1], n_pad, axis=0)])
-    scfg = eng.spatial_cfg
-    stencil = SV.StencilSpec(alpha=scfg.alpha,
-                             neighbors=_spatial_neighbors(
-                                 eng, imgs.ndim - 1))
-    return SV.batch_problems(jnp.asarray(imgs), stencil=stencil,
-                             cfg=scfg), scfg
-
-
 def _spatial_label_dtype(n_clusters: int) -> np.dtype:
     """The dtype of the spatial route's label maps: uint8 holds every
     label 0..c-1 exactly for c <= 256, and moves a quarter of int32's
     bytes from the chip; int32 above that."""
     return np.dtype(np.uint8 if n_clusters <= 256 else np.int32)
-
-
-def _materialize_spatial(eng, q, centers, n_iters, cache_hit):
-    # Single-request face of the batch materializer (the route registers
-    # materialize_batch, so flush() normally never calls this; it exists
-    # for API symmetry and must not drift from the batch version).
-    return _materialize_spatial_batch(eng, [q], np.asarray(centers)[None],
-                                      np.asarray([n_iters]))[0]
-
-
-def _materialize_spatial_batch(eng, chunk, centers, n_iters):
-    """One vmapped stencil-membership + argmax launch for the whole
-    chunk: the per-request labeling is as stencil-heavy as an FCM_S
-    iteration, so batching it is what keeps served spatial throughput
-    at the batched-fit level."""
-    import jax
-
-    scfg = eng.spatial_cfg
-    neighbors = _spatial_neighbors(eng, chunk[0].pixels.ndim)
-    imgs = jnp.asarray(np.stack([q.pixels for q in chunk]), jnp.float32)
-    u = jax.vmap(lambda im, v: SP.spatial_membership(
-        im, v, scfg.m, scfg.alpha, neighbors))(
-            imgs, jnp.asarray(centers[:len(chunk)]))
-    labels = np.asarray(jnp.argmax(u, axis=1).astype(
-        _spatial_label_dtype(scfg.n_clusters)))
-    return [SegmentationResult(q.request_id, labels[i],
-                               np.asarray(centers[i]), int(n_iters[i]),
-                               False, method="spatial")
-            for i, q in enumerate(chunk)]
 
 
 def _spatial_program_key(eng, chunk):
@@ -702,14 +621,13 @@ def _spatial_program_key(eng, chunk):
     return ("sp", dtype) + chunk[0].pixels.shape
 
 
-def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
+def _make_spatial_program(eng, key, bucket,
+                          reference=False) -> "RouteProgram":
     """The fused spatial pipeline: stack -> batched FCM_S solve ->
     stencil-membership labeling, ONE jitted dispatch per flush. On TPU
     the solve stage is the VMEM-resident whole-solve stencil kernel
-    (when the grid fits its bounds); off-TPU it is the vmapped
-    reference stencil loop — either way the route sheds the
-    per-stage host synchronization that made spatial serving the
-    highest-overhead route."""
+    (when the grid fits its bounds); off-TPU, and in the reference
+    program, it is the vmapped reference stencil loop."""
     dtype, shape = key[1], key[2:]
     scfg = eng.spatial_cfg
     c, m = scfg.n_clusters, float(scfg.m)
@@ -718,8 +636,8 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
     eps, max_iters = float(scfg.eps), int(scfg.max_iters)
     label_dtype = _spatial_label_dtype(c)
     platform = jax.default_backend()
-    impl = kops.select_step("stencil", platform=platform, batched=True,
-                            n_rows=KR.stencil_pixels(shape), c=c).name
+    impl = _impl("stencil", reference, batched=True,
+                 n_rows=KR.stencil_pixels(shape), c=c)
 
     def launch_fn(imgs):
         # Widen on the chip: uint8 -> float32 is exact, so the solve sees
@@ -746,8 +664,8 @@ def _make_spatial_program(eng, key, bucket) -> "RouteProgram":
         imgs = np.empty((bucket_,) + shape, dtype)
         for i, q in enumerate(chunk):
             imgs[i] = q.pixels
-        # Padding lanes replay the first image (frozen-lane masking makes
-        # them cost one lane of compute; dropped on output).
+        # Padding lanes replay the first image: each runs lane 0's whole
+        # solve, and its answer is dropped.
         for i in range(len(chunk), bucket_):
             imgs[i] = imgs[0]
         # uint8 goes flat, (bucket, pixels), as the histogram route's
@@ -785,59 +703,80 @@ def _ingest_superpixel(eng, img, rid) -> _PendingSuperpixel:
                               np.asarray(comp.label_map), comp.slic_iters)
 
 
-def _build_superpixel(eng, chunk, bucket):
-    k, d = chunk[0].features.shape
-    feats = np.stack([q.features for q in chunk])
-    ws = np.stack([q.weights for q in chunk])
-    n_pad = bucket - len(chunk)
-    if n_pad:
-        # Benign padding lanes: a unit-weight feature ramp converges in a
-        # handful of iterations and is dropped on output.
-        ramp = np.broadcast_to(
-            np.linspace(0.0, 1.0, k, dtype=np.float32)[:, None], (k, d))
-        feats = np.concatenate([feats, np.broadcast_to(ramp, (n_pad, k, d))])
-        ws = np.concatenate([ws, np.ones((n_pad, k), np.float32)])
-    # The superpixel config governs the fit (a caller-supplied one must
-    # win over self.cfg, not just steer the compression).
-    return SV.batch_problems(jnp.asarray(feats), jnp.asarray(ws),
-                             cfg=eng.superpixel_cfg), eng.superpixel_cfg
+def _superpixel_program_key(eng, chunk):
+    return ("spx",) + chunk[0].features.shape  # bucket_key groups by shape
 
 
-def _materialize_superpixel(eng, q, centers, n_iters, cache_hit):
-    sp_labels = np.asarray(F.labels_from_centers(jnp.asarray(q.features),
-                                                 jnp.asarray(centers)))
-    labels = sp_labels[q.label_map]
-    return SegmentationResult(q.request_id, labels, np.asarray(centers),
-                              n_iters, cache_hit, method="superpixel")
+def _make_superpixel_program(eng, key, bucket,
+                             reference=False) -> RouteProgram:
+    """The superpixel fit: the weighted (K, D) solve of every lane and
+    its per-superpixel labels in ONE dispatch (SLIC ran at ingest); the
+    host maps each lane's labels through its pixel -> superpixel map.
+    The superpixel config governs the fit (a caller-supplied one must
+    win over self.cfg, not just steer the compression)."""
+    k, d = key[1:]
+    scfg = eng.superpixel_cfg
+    c, m = scfg.n_clusters, float(scfg.m)
+    eps, max_iters = float(scfg.eps), int(scfg.max_iters)
+    platform = jax.default_backend()
+    impl = _impl("flat", reference, n_feat=d, batched=True, n_rows=k, c=c)
+
+    def launch_fn(feats, ws):
+        v, delta, iters, total = SV.flat_batched_solve(
+            feats, ws, c, m, eps, max_iters, impl=impl)
+        return v, delta, iters, total, \
+            jax.vmap(F.labels_from_centers)(feats, v)
+
+    launch = _jit_launch(
+        eng, bucket,
+        ("superpixel", platform, bucket, key, c, m, eps, max_iters, impl),
+        launch_fn, 2)
+
+    def gather(eng_, chunk, bucket_):
+        feats = np.empty((bucket_, k, d), np.float32)
+        ws = np.empty((bucket_, k), np.float32)
+        for i, q in enumerate(chunk):
+            feats[i], ws[i] = q.features, q.weights
+        # Padding lanes replay the first payload: each runs lane 0's
+        # whole solve, and its answer is dropped.
+        feats[len(chunk):], ws[len(chunk):] = feats[0], ws[0]
+        return feats, ws
+
+    def scatter(eng_, chunk, outs):
+        v, delta, iters, total, sp_labels = outs
+        centers = np.asarray(v)
+        iters_np = np.asarray(iters)
+        sp_np = np.asarray(sp_labels)
+        res = [SegmentationResult(q.request_id, sp_np[i][q.label_map],
+                                  centers[i], int(iters_np[i]), False,
+                                  method="superpixel")
+               for i, q in enumerate(chunk)]
+        return res, centers, iters_np, int(total), np.asarray(delta)
+
+    return RouteProgram(gather, launch, scatter, (f"flat/{impl}",))
 
 
 register_route(RouteSpec(
     name="histogram", ingest=_ingest_histogram,
     bucket_key=lambda eng, p: ("hist",),
-    build_problem=_build_histogram, materialize=_materialize_histogram,
-    cacheable=True,
     program_key=_histogram_program_key,
-    make_program=_make_histogram_program))
+    make_program=_make_histogram_program,
+    materialize=_materialize_histogram, cacheable=True))
 register_route(RouteSpec(
     name="pixel", ingest=_ingest_pixel,
     bucket_key=lambda eng, p: ("pixel",) + p.pixels.shape,
-    build_problem=_build_pixel, materialize=_materialize_pixel,
-    stats_prefix="pixel",
     program_key=_pixel_program_key,
-    make_program=_make_pixel_program))
+    make_program=_make_pixel_program, stats_prefix="pixel"))
 register_route(RouteSpec(
     name="spatial", ingest=_ingest_spatial,
     bucket_key=lambda eng, p: ("spatial",) + p.pixels.shape,
-    build_problem=_build_spatial, materialize=_materialize_spatial,
-    materialize_batch=_materialize_spatial_batch,
-    stats_prefix="spatial",
     program_key=_spatial_program_key,
-    make_program=_make_spatial_program))
+    make_program=_make_spatial_program, stats_prefix="spatial"))
 register_route(RouteSpec(
     name="superpixel", ingest=_ingest_superpixel,
     bucket_key=lambda eng, p: ("superpixel",) + p.features.shape,
-    build_problem=_build_superpixel, materialize=_materialize_superpixel,
-    stats_prefix="superpixel"))
+    program_key=_superpixel_program_key,
+    make_program=_make_superpixel_program, stats_prefix="superpixel"))
 
 #: The serving routes, in registration order (the README routing table).
 METHODS = tuple(ROUTES)
@@ -848,9 +787,9 @@ class FCMServeEngine:
 
     ``submit`` ingests an image through its route (any 2-D/3-D shape,
     8-bit-range values) and either answers from the cache or queues it.
-    ``flush`` drains every route's queue through bucketed
-    ``solve_batched`` calls. ``segment`` is the submit-all-then-flush
-    convenience wrapper.
+    ``flush`` drains every route's queue through bucketed route-program
+    launches. ``segment`` is the submit-all-then-flush convenience
+    wrapper.
 
     **Async admission** (the continuous-batching front door):
     ``submit_async`` queues through the same per-route queues but hands
@@ -915,6 +854,8 @@ class FCMServeEngine:
         #: bucket, payload-shape key); the generation key is what makes
         #: re-registered routes drop their stale programs.
         self._programs: Dict[Hashable, RouteProgram] = {}
+        #: the same for reference programs (not in compiled_programs).
+        self._ref_programs: Dict[Hashable, RouteProgram] = {}
         self._next_id = 0
         # All engine instrumentation lives on the obs layer: a private
         # MetricsRegistry (stats() renders the legacy flat keys from it)
@@ -931,9 +872,9 @@ class FCMServeEngine:
         self.retries = int(retries)
         self.retry_backoff_s = float(retry_backoff_s)
         #: consecutive post-retry launch failures before a route's
-        #: compiled program is circuit-broken to the staged reference
-        #: path; after breaker_cooldown_s one half-open probe launch
-        #: tests recovery.
+        #: compiled program is circuit-broken to its reference program;
+        #: after breaker_cooldown_s one half-open probe launch tests
+        #: recovery.
         self.breaker_threshold = int(breaker_threshold)
         self.breaker_cooldown_s = float(breaker_cooldown_s)
         #: queued-request ceiling: submits beyond it shed the lowest-
@@ -1580,33 +1521,41 @@ class FCMServeEngine:
         return self.batch_sizes[-1]
 
     def _program_for(self, route: RouteSpec,
-                     chunk: List[Any], bucket: int) -> Optional[RouteProgram]:
-        """The compiled single-dispatch program this chunk can ride, or
-        None (route has no programs / chunk shape has none). Programs
-        are cached per (route generation, mesh generation, bucket,
-        shape key); stale generations — a re-registered route OR a
-        swapped mesh — are purged here."""
-        if route.make_program is None or route.program_key is None:
-            return None
+                     chunk: List[Any], bucket: int) -> RouteProgram:
+        """The compiled single-dispatch program this chunk rides."""
+        return self._cached_program(self._programs, route, chunk, bucket,
+                                    reference=False)
+
+    def _reference_program_for(self, route: RouteSpec, chunk: List[Any],
+                               bucket: int) -> RouteProgram:
+        """The chunk's reference program: the same gather, launch body
+        and scatter on the registry's reference impls. Degraded chunks
+        and salvaged lanes run it."""
+        return self._cached_program(self._ref_programs, route, chunk,
+                                    bucket, reference=True)
+
+    def _cached_program(self, cache: Dict[Hashable, RouteProgram],
+                        route: RouteSpec, chunk: List[Any], bucket: int,
+                        reference: bool) -> RouteProgram:
+        """Programs are cached per (route generation, mesh generation,
+        bucket, shape key); stale generations — a re-registered route OR
+        a swapped mesh — are purged here."""
         key = route.program_key(self, chunk)
-        if key is None:
-            return None
         gen = _ROUTE_GEN[route.name]
-        stale = [k for k in self._programs
+        stale = [k for k in cache
                  if (k[0] == route.name and k[1] != gen)
                  or k[2] != self._mesh_gen]
         for k in stale:
-            del self._programs[k]
+            del cache[k]
         full_key = (route.name, gen, self._mesh_gen, bucket, key)
-        prog = self._programs.get(full_key)
+        prog = cache.get(full_key)
         if prog is None:
-            prog = route.make_program(self, key, bucket)
-            self._programs[full_key] = prog
+            prog = route.make_program(self, key, bucket, reference=reference)
+            cache[full_key] = prog
             # Same bound rationale as _LAUNCH_CACHE: size-keyed program
             # flavors must not accumulate one entry per payload size.
-            while len(self._programs) > _LAUNCH_CACHE_SIZE:
-                oldest = next(iter(self._programs))
-                del self._programs[oldest]
+            while len(cache) > _LAUNCH_CACHE_SIZE:
+                del cache[next(iter(cache))]
         return prog
 
     # -- circuit breaker + retry (the graceful-degradation ladder) ---------
@@ -1668,12 +1617,15 @@ class FCMServeEngine:
                 self._set_breaker(route_name, b, "open")
 
     def _launch_attempts(self, route: RouteSpec, prog: RouteProgram,
-                         inputs: Tuple, bucket: int) -> Tuple[Tuple, float]:
+                         inputs: Tuple, bucket: int,
+                         reference: bool = False) -> Tuple[Tuple, float]:
         """One program launch under the bounded-retry policy: transient
         failures (injected faults, launch-time runtime errors) retry up
         to ``retries`` times with exponential backoff; programming
         errors (ValueError/TypeError) and the final failure propagate —
-        the caller advances the breaker and degrades the chunk. Each
+        the caller advances the breaker and degrades the chunk. A
+        ``reference`` program, the last resort, makes one attempt and
+        passes no ``launch`` fault site. Each
         attempt first puts the host ``inputs`` on the device with the
         launch's sharding (an ``h2d`` span, fenced: the batch axis split
         over the mesh ``_jit_launch`` shards this bucket over, so no
@@ -1687,7 +1639,7 @@ class FCMServeEngine:
         h2d_s = 0.0
         while True:
             try:
-                if self._faults is not None:
+                if self._faults is not None and not reference:
                     self._faults.maybe_fail("launch", route=route.name)
                 with self.tracer.span("h2d", route=route.name) as sp:
                     dev = sp.fence(jax.device_put(inputs, sharding))
@@ -1698,7 +1650,7 @@ class FCMServeEngine:
             except (ValueError, TypeError):
                 raise
             except Exception:
-                if attempt >= self.retries:
+                if reference or attempt >= self.retries:
                     raise
                 self._route_counter("retries", route.name).inc()
                 time.sleep(self.retry_backoff_s * (2 ** attempt))
@@ -1722,69 +1674,81 @@ class FCMServeEngine:
 
     def _route_cfg(self, route: RouteSpec):
         """The config whose eps/max_iters govern this route's fits."""
-        if route.name == "spatial":
-            return self.spatial_cfg
-        if route.name == "superpixel":
-            return self.superpixel_cfg
-        return self.cfg
+        return {"spatial": self.spatial_cfg,
+                "superpixel": self.superpixel_cfg}.get(route.name, self.cfg)
+
+    def _gather_launch(self, route: RouteSpec, prog: RouteProgram,
+                       chunk: List[Any], bucket: int,
+                       reference: bool = False) -> Tuple[Tuple, Tuple, Tuple]:
+        """Stack ``chunk`` with the program's gather and launch it, in
+        the ``gather`` and fenced ``launch`` spans; returns the device
+        outputs, the host inputs and the (gather, launch, h2d) seconds."""
+        with self.tracer.span("gather", route=route.name) as sp_g:
+            inputs = prog.gather(self, chunk, bucket)
+        with self.tracer.span("launch", route=route.name) as sp_s:
+            outs, h2d_s = self._launch_attempts(route, prog, inputs, bucket,
+                                                reference)
+            outs = sp_s.fence(outs)
+        return outs, inputs, (sp_g.wall_s, sp_s.wall_s, h2d_s)
+
+    def _scatter(self, route: RouteSpec, prog: RouteProgram,
+                 chunk: List[Any], outs: Tuple) -> Tuple[Tuple, Tuple, Tuple]:
+        """Fetch a launch's outputs (the ``d2h`` span) and unpack them with
+        the program's scatter (the ``scatter`` span); returns the scatter's
+        tuple, the fetched outputs and the (scatter, d2h) seconds."""
+        with self.tracer.span("scatter", route=route.name) as sp_m:
+            with self.tracer.span("d2h", route=route.name) as sp_d:
+                outs = jax.device_get(outs)
+            scattered = prog.scatter(self, chunk, outs)
+        return scattered, outs, (sp_m.wall_s, sp_d.wall_s)
 
     def _salvage_requests(self, route: RouteSpec, bad: List[Any],
                           results: Dict[int, SegmentationResult],
                           fitted: Dict[bytes, np.ndarray]) -> None:
-        """Re-solve poisoned requests on the reference backend in their
-        own mini-bucket and finish them from the clean centers — one
-        non-finite lane degrades to a per-request reference re-solve
-        instead of failing (or infecting) its whole batch. A request
-        still non-finite after the reference pass fails with
+        """Re-solve poisoned requests on the route's reference program
+        in their own mini-bucket and finish them from the clean centers
+        — one non-finite lane degrades to a reference re-solve instead
+        of failing (or infecting) its whole batch. A request still
+        non-finite after the reference pass fails with
         :class:`SolveFailed` (async: typed error on its future; sync:
         raised to the flushing caller)."""
         self._route_counter("salvaged", route.name).inc(len(bad))
         bucket = self._bucket_for(len(bad))
-        problem, cfg = route.build_problem(self, bad, bucket)
-        res = SV.solve_batched(problem, cfg, backend="reference")
-        centers = np.asarray(res.centers)
-        healthy = (np.ones(len(bad), bool) if res.healthy is None
-                   else np.asarray(res.healthy))
-        conv = (None if res.converged is None
-                else np.asarray(res.converged))
+        prog = self._reference_program_for(route, bad, bucket)
+        outs = self._gather_launch(route, prog, bad, bucket,
+                                   reference=True)[0]
+        res_list, centers, n_iters = self._scatter(route, prog, bad,
+                                                   outs)[0][:3]
+        max_iters = int(self._route_cfg(route).max_iters)
         doomed: Optional[BaseException] = None
-        for lane, p in enumerate(bad):
-            if not bool(healthy[lane]):
+        for lane, (p, r) in enumerate(zip(bad, res_list)):
+            if not np.isfinite(centers[lane]).all():
                 err = SolveFailed(
                     f"request {p.request_id}: non-finite centers even "
-                    f"on the reference backend")
+                    f"on the reference program")
                 if not self._fail_request(p, err) and doomed is None:
                     doomed = err
                 continue
-            r = route.materialize(self, p, centers[lane],
-                                  int(res.n_iters[lane]), False)
-            if conv is not None:
-                r.converged = bool(conv[lane])
+            r.converged = bool(n_iters[lane] < max_iters)
             self._finish(route, results, r)
-            if route.cacheable and getattr(p, "key", None) is not None:
+            if route.cacheable and self.cache_size > 0:
                 fitted[p.key] = centers[lane]
-                if self.cache_size > 0 and p.hist is not None:
-                    self._cache_put(p.key, centers[lane], p.hist)
+                self._cache_put(p.key, centers[lane], p.hist)
         if doomed is not None:
             raise doomed
 
     def _run_bucket(self, route: RouteSpec, chunk: List[Any], bucket: int,
                     results: Dict[int, SegmentationResult],
                     fitted: Dict[bytes, np.ndarray]):
-        prog = self._program_for(route, chunk, bucket)
-        use_prog = prog is not None and self._breaker_allows(route.name)
-        degraded = prog is not None and not use_prog
-        n_iters = None
-        deltas = None
+        fast = self._breaker_allows(route.name)
+        prog = self._program_for(route, chunk, bucket) if fast else None
         max_iters = int(self._route_cfg(route).max_iters)
-        bad_pend: List[Any] = []
-        bad_ids: set = set()
-        mesh = self._mesh_for_bucket(bucket) if use_prog else None
+        mesh = self._mesh_for_bucket(bucket)
         shards = 1 if mesh is None else mesh.size
         with self.tracer.span("bucket",
                               annotate=("route", "bucket", "shards"),
                               route=route.name, bucket=bucket,
-                              shards=shards, n=len(chunk), fused=use_prog,
+                              shards=shards, n=len(chunk), fused=fast,
                               requests=[p.request_id for p in chunk]):
             # Queue wait: each real lane's submit -> its batch's gather.
             now = time.perf_counter()
@@ -1794,138 +1758,74 @@ class FCMServeEngine:
                 if sub is not None:
                     waited += now - sub[0]
             self._queue_wait(route.name).inc(waited)
-            if use_prog:
+            # ``inputs`` stays referenced until the batch is done: freed
+            # before the labels' device_get, it cost the closed-loop
+            # histogram cell ~12% on a v5e chip (PERF.md §6).
+            outs = None
+            if fast:
                 # Device-resident fast path: host-side stacking, ONE
-                # jitted dispatch (ingest-binning + solve + defuzzify),
-                # unpack. Launch failures surviving the retry budget
-                # advance the breaker and degrade this chunk to the
-                # staged reference path below.
-                with self.tracer.span("gather", route=route.name) as sp_g:
-                    inputs = prog.gather(self, chunk, bucket)
+                # jitted dispatch (ingest-binning + solve + defuzzify).
+                # Launch failures surviving the retry budget advance the
+                # breaker and degrade this chunk to the reference program.
                 try:
-                    with self.tracer.span("launch",
-                                          route=route.name) as sp_s:
-                        outs, h2d_s = self._launch_attempts(
-                            route, prog, inputs, bucket)
-                        outs = sp_s.fence(outs)
+                    outs, inputs, secs = self._gather_launch(
+                        route, prog, chunk, bucket)
                 except (ValueError, TypeError):
                     raise       # programming errors are not transient
                 except Exception:
                     self._breaker_failure(route.name)
                     self._route_counter("degraded", route.name).inc()
-                    use_prog, degraded = False, True
                 else:
                     self._breaker_success(route.name)
-                    with self.tracer.span("scatter",
-                                          route=route.name) as sp_m:
-                        with self.tracer.span("d2h",
-                                              route=route.name) as sp_d:
-                            outs = jax.device_get(outs)
-                        scattered = prog.scatter(self, chunk, outs)
-                    res_list, centers, n_iters, total_iters = scattered[:4]
-                    if len(scattered) > 4:      # telemetry-aware program
-                        deltas = np.asarray(scattered[4])
-                    if self._faults is not None:
-                        centers = np.asarray(self._faults.corrupt(
-                            "solve", centers, route=route.name))
-                    finite = np.isfinite(
-                        centers.reshape(centers.shape[0], -1)).all(axis=1)
-                    iters_np = np.asarray(n_iters)
-                    if shards > 1:
-                        self._count_shards(route.name, iters_np, shards)
-                    for lane, (p, r) in enumerate(zip(chunk, res_list)):
-                        if not bool(finite[lane]):
-                            bad_pend.append(p)
-                            bad_ids.add(p.request_id)
-                            continue
-                        r.converged = bool(iters_np[lane] < max_iters)
-                        self._finish(route, results, r)
-                    self._stage_seconds(route.name, "gather").inc(
-                        sp_g.wall_s)
-                    self._stage_seconds(route.name, "solve").inc(
-                        sp_s.wall_s)
-                    self._stage_seconds(route.name, "h2d").inc(h2d_s)
-                    self._stage_seconds(route.name, "materialize").inc(
-                        sp_m.wall_s)
-                    self._stage_seconds(route.name, "d2h").inc(
-                        sp_d.wall_s)
-                    self._route_counter("d2h_bytes", route.name).inc(
-                        sum(x.nbytes for x in jax.tree_util.tree_leaves(outs)))
-            if not use_prog:
-                with self.tracer.span("build", route=route.name) as sp_g:
-                    problem, cfg = route.build_problem(self, chunk, bucket)
-                with self.tracer.span("solve", route=route.name) as sp_s:
-                    res = sp_s.fence(SV.solve_batched(
-                        problem, cfg,
-                        backend="reference" if degraded else "auto"))
-                with self.tracer.span("materialize",
-                                      route=route.name) as sp_m:
-                    centers = np.asarray(res.centers)
-                    if self._faults is not None:
-                        centers = np.asarray(self._faults.corrupt(
-                            "solve", centers, route=route.name))
-                    total_iters = int(res.total_iters)
-                    n_iters = res.n_iters
-                    deltas = np.asarray(res.final_delta)
-                    finite = np.isfinite(
-                        centers.reshape(centers.shape[0], -1)).all(axis=1)
-                    conv = (None if res.converged is None
-                            else np.asarray(res.converged))
-                    good: List[Tuple[int, Any]] = []
-                    for lane, p in enumerate(chunk):
-                        if bool(finite[lane]):
-                            good.append((lane, p))
-                        else:
-                            bad_pend.append(p)
-                            bad_ids.add(p.request_id)
-                    if route.materialize_batch is not None:
-                        gchunk = [p for _, p in good]
-                        if gchunk:
-                            lanes = [lane for lane, _ in good]
-                            for j, r in enumerate(route.materialize_batch(
-                                    self, gchunk, centers[lanes],
-                                    res.n_iters[lanes])):
-                                if conv is not None:
-                                    r.converged = bool(conv[lanes[j]])
-                                self._finish(route, results, r)
-                    else:
-                        for lane, p in good:
-                            r = route.materialize(
-                                self, p, centers[lane],
-                                int(res.n_iters[lane]), False)
-                            if conv is not None:
-                                r.converged = bool(conv[lane])
-                            self._finish(route, results, r)
-                self._stage_seconds(route.name, "gather").inc(sp_g.wall_s)
-                self._stage_seconds(route.name, "solve").inc(sp_s.wall_s)
-                self._stage_seconds(route.name, "materialize").inc(
-                    sp_m.wall_s)
-            if bad_pend:
+            if outs is None:
+                prog = self._reference_program_for(route, chunk, bucket)
+                outs, inputs, secs = self._gather_launch(
+                    route, prog, chunk, bucket, reference=True)
+            scattered, outs, unpack = self._scatter(route, prog, chunk, outs)
+            res_list, centers, n_iters, total_iters, deltas = scattered
+            if self._faults is not None:
+                centers = np.asarray(self._faults.corrupt(
+                    "solve", centers, route=route.name))
+            finite = np.isfinite(
+                centers.reshape(centers.shape[0], -1)).all(axis=1)
+            n_iters = np.asarray(n_iters)
+            if shards > 1:
+                self._count_shards(route.name, n_iters, shards)
+            bad: List[Any] = []
+            for lane, (p, r) in enumerate(zip(chunk, res_list)):
+                if not finite[lane]:
+                    bad.append(p)
+                    continue
+                r.converged = bool(n_iters[lane] < max_iters)
+                self._finish(route, results, r)
+            # Accounting after the futures resolve: their clients go on.
+            for stage, s in zip(("gather", "solve", "h2d", "materialize",
+                                 "d2h"), secs + unpack):
+                self._stage_seconds(route.name, stage).inc(s)
+            self._route_counter("d2h_bytes", route.name).inc(
+                sum(x.nbytes for x in jax.tree_util.tree_leaves(outs)))
+            if bad:
                 # Poisoned lanes (injected or real non-finite centers):
-                # per-request reference re-solve, healthy batchmates
-                # already finished untouched above.
+                # reference re-solve, healthy batchmates already
+                # finished untouched above.
                 with self.tracer.span("salvage", route=route.name,
-                                      n=len(bad_pend)):
-                    self._salvage_requests(route, bad_pend, results,
-                                           fitted)
+                                      n=len(bad)):
+                    self._salvage_requests(route, bad, results, fitted)
         self._route_counter("batches", route.name).inc()
         self._route_counter("images", route.name).inc(len(chunk))
         self._route_counter("padded", route.name).inc(bucket - len(chunk))
         self._route_counter("iters", route.name).inc(int(total_iters))
         self._occupancy_hist(route.name).record(len(chunk) / bucket)
         # Convergence telemetry: one sample per *real* lane (padding
-        # lanes converge artificially fast and would skew the mix).
-        if n_iters is not None:
-            h = self._iters_hist(route.name)
-            for it in np.asarray(n_iters)[:len(chunk)]:
-                h.record(int(it))
-        if deltas is not None and len(deltas):
-            self.metrics.gauge("route.last_final_delta",
-                               route=route.name).set(
-                float(np.max(deltas[:len(chunk)])))
+        # lanes would skew the mix).
+        h = self._iters_hist(route.name)
+        for it in n_iters[:len(chunk)]:
+            h.record(int(it))
+        self.metrics.gauge("route.last_final_delta", route=route.name).set(
+            float(np.max(deltas[:len(chunk)])))
         if route.cacheable and self.cache_size > 0:
             for lane, p in enumerate(chunk):
-                if p.request_id in bad_ids:
+                if not finite[lane]:
                     continue    # poisoned centers must never enter the LRU
                 fitted[p.key] = centers[lane]
                 self._cache_put(p.key, centers[lane], p.hist)

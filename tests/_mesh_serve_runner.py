@@ -1,10 +1,12 @@
 """Subprocess entry for mesh-serving tests: an FCMServeEngine with its
 RouteProgram launches sharded over 8 fake host devices must serve
 results identical to the single-device engine — through both the sync
-and async front doors — and set_mesh must never serve a stale program.
+and async front doors, and through the reference programs a held-open
+breaker degrades to — and set_mesh must never serve a stale program.
 Prints MESH_SERVE_OK on success."""
 import os
 import sys
+import time
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
@@ -52,6 +54,22 @@ def main():
     meshed.drain()
     for f, b in zip(futs, ref):
         _check_same(f.result(timeout=30), b)
+
+    # A held-open breaker sends every chunk to the reference program,
+    # sharded over the mesh as the fast one is: the same answers.
+    degraded = FCMServeEngine(cfg, batch_sizes=(1, 8), cache_size=0,
+                              mesh=mesh, breaker_cooldown_s=1e9)
+    with degraded._lock:
+        br = degraded._breaker("histogram")
+        br["opened_t"] = time.perf_counter()
+        degraded._set_breaker("histogram", br, "open")
+    for a, b in zip(degraded.segment(imgs), ref):
+        _check_same(a, b)
+    assert degraded.stats()["compiled_programs"] == 0
+    # 11 requests: buckets of 8 and 3 (padded to 8), both sharded.
+    assert degraded.metrics.counter("route.sharded_batches",
+                                    route="histogram").value == 2
+    degraded.shutdown()
 
     # set_mesh(None) detaches: programs recompile (new generation) and
     # keep serving identical results.

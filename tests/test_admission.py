@@ -223,8 +223,9 @@ def test_concurrent_shutdown_and_erroring_route_exactly_once():
     # resolve exactly once (typed error or EngineShutdown), with no
     # "resolved twice" RuntimeError escaping either resolver and no
     # future left pending.
+    import dataclasses
+
     from repro import faults as FI
-    from repro.core import solver as SV
 
     plan = FI.FaultPlan(seed=0, specs=(
         FI.FaultSpec(site="launch", kind="error", times=None),))
@@ -235,8 +236,10 @@ def test_concurrent_shutdown_and_erroring_route_exactly_once():
     def boom(*a, **k):
         raise ValueError("solver exploded")
 
-    orig = SV.solve_batched
-    SV.solve_batched = boom     # degraded fallback path raises too
+    reference_program_for = eng._reference_program_for
+    # The degraded chunk's reference launch raises too.
+    eng._reference_program_for = lambda *a: dataclasses.replace(
+        reference_program_for(*a), launch=boom)
     errs = []
 
     def flusher():
@@ -245,13 +248,10 @@ def test_concurrent_shutdown_and_erroring_route_exactly_once():
         except BaseException as e:  # noqa: BLE001
             errs.append(e)
 
-    try:
-        t = threading.Thread(target=flusher)
-        t.start()
-        eng.shutdown(drain=True)
-        t.join()
-    finally:
-        SV.solve_batched = orig
+    t = threading.Thread(target=flusher)
+    t.start()
+    eng.shutdown(drain=True)
+    t.join()
     assert errs == []                       # no "resolved twice" escaped
     for f in futs:
         assert f.done()

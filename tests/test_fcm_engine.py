@@ -2,6 +2,7 @@
 against the single-image histogram fit, and the device-resident route
 programs (single-dispatch serving, program-cache lifecycle)."""
 import dataclasses
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -404,28 +405,40 @@ def test_pixel_requests_batch_across_requests():
 # Device-resident route programs (single-dispatch serving pipeline)
 # ---------------------------------------------------------------------------
 
-def test_fused_program_matches_staged_route_path():
-    """The single-dispatch histogram program must serve exactly what the
-    staged build_problem -> solve_batched -> materialize path serves."""
-    from repro.serving import fcm_engine as E
+def _reference_engine(route, cfg=CFG, **kw):
+    """An engine whose ``route`` breaker is held open: every chunk of the
+    route runs its reference program (the registry's reference impls
+    behind the same gather and scatter)."""
+    eng = FCMServeEngine(cfg, breaker_cooldown_s=1e9, **kw)
+    with eng._lock:
+        b = eng._breaker(route)
+        b["opened_t"] = time.perf_counter()
+        eng._set_breaker(route, b, "open")
+    return eng
 
+
+def _last_bucket(eng, route):
+    return [c for t in eng.tracer.traces() if t["name"] == "flush"
+            for c in t["children"] if c["name"] == "bucket"
+            and c["attrs"]["route"] == route][-1]
+
+
+def test_fused_program_matches_staged_route_path():
+    """The histogram route's fast program must serve exactly what its
+    reference program (registry reference impls, same gather and
+    scatter) serves."""
     imgs = [phantom.phantom_slice(48, 56, noise=2.0 + i, seed=i)[0]
             for i in range(5)]
     fused = FCMServeEngine(CFG, batch_sizes=(8,), cache_size=0)
     res_fused = fused.segment(imgs)
     assert fused.stats()["compiled_programs"] == 1
 
-    # Staged comparator: same route minus the program hooks.
-    base = E.ROUTES["histogram"]
-    E.register_route(dataclasses.replace(base, program_key=None,
-                                         make_program=None))
-    try:
-        staged = FCMServeEngine(CFG, batch_sizes=(8,), cache_size=0)
-        res_staged = staged.segment(imgs)
-        assert staged.stats()["compiled_programs"] == 0
-    finally:
-        E.register_route(base)
-    for f, s in zip(res_fused, res_staged):
+    ref = _reference_engine("histogram", batch_sizes=(8,), cache_size=0)
+    res_ref = ref.segment(imgs)
+    assert ref.stats()["compiled_programs"] == 0
+    assert len(ref._ref_programs) == 1
+    assert ref.stats()["fault_tolerance"]["degraded"]["histogram"] == 0
+    for f, s in zip(res_fused, res_ref):
         np.testing.assert_allclose(f.centers, s.centers, atol=1e-5)
         assert f.n_iters == s.n_iters
         assert (f.labels == s.labels).all()
@@ -449,34 +462,29 @@ def test_fused_program_mixed_sizes_one_dispatch():
 
 
 def test_fused_spatial_program_matches_staged_route_path():
-    """The spatial route now compiles a fused stencil program (whole
-    batched convergence in one launch); it must serve exactly what the
-    staged build_problem -> solve_batched -> materialize path serves."""
-    from repro.serving import fcm_engine as E
-
+    """The spatial route compiles a fused stencil program (whole
+    batched convergence in one launch); it must serve exactly what its
+    reference program serves, through the same spans."""
     imgs = [phantom.phantom_slice(40, 48, noise=2.0 + i, seed=i)[0]
             for i in range(3)]
     fused = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0,
                            trace_ring=8)
     res_fused = fused.segment(imgs, method="spatial")
     assert fused.stats()["compiled_programs"] == 1
-    buckets = [c for t in fused.tracer.traces() if t["name"] == "flush"
-               for c in t["children"] if c["name"] == "bucket"
-               and c["attrs"]["route"] == "spatial"]
-    assert buckets and buckets[-1]["attrs"]["fused"] is True
-    assert [c["name"] for c in buckets[-1]["children"]] == [
+    bucket = _last_bucket(fused, "spatial")
+    assert bucket["attrs"]["fused"] is True
+    assert [c["name"] for c in bucket["children"]] == [
         "gather", "launch", "scatter"]
 
-    base = E.ROUTES["spatial"]
-    E.register_route(dataclasses.replace(base, program_key=None,
-                                         make_program=None))
-    try:
-        staged = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
-        res_staged = staged.segment(imgs, method="spatial")
-        assert staged.stats()["compiled_programs"] == 0
-    finally:
-        E.register_route(base)
-    for f, s in zip(res_fused, res_staged):
+    ref = _reference_engine("spatial", batch_sizes=(4,), cache_size=0,
+                            trace_ring=8)
+    res_ref = ref.segment(imgs, method="spatial")
+    assert ref.stats()["compiled_programs"] == 0
+    bucket = _last_bucket(ref, "spatial")
+    assert bucket["attrs"]["fused"] is False
+    assert [c["name"] for c in bucket["children"]] == [
+        "gather", "launch", "scatter"]
+    for f, s in zip(res_fused, res_ref):
         np.testing.assert_allclose(f.centers, s.centers, atol=1e-5)
         assert f.n_iters == s.n_iters
         assert (f.labels == s.labels).all()
@@ -486,6 +494,72 @@ def _noisy_slices(n=3, h=40, w=48):
     return [phantom.noisy_phantom_slice(h, w, noise=8.0 + 3 * i,
                                         impulse=0.04, seed=i)[0]
             for i in range(n)]
+
+
+def _route_payloads(route):
+    if route == "superpixel":
+        return [phantom.phantom_slice_rgb(48, 48, noise=3.0 + i,
+                                          seed=40 + i)[0] for i in range(3)]
+    if route == "spatial":
+        return _noisy_slices()
+    return [phantom.phantom_slice(40, 48, noise=2.0 + i, seed=40 + i)[0]
+            for i in range(3)]
+
+
+@pytest.mark.parametrize("route", ["histogram", "pixel", "spatial",
+                                   "superpixel"])
+def test_open_breaker_serves_each_route_through_its_reference_program(
+        route):
+    """A breaker held open sends every chunk of the route to its
+    reference program, through the fast program's spans and counters;
+    the answers match the fast program's. The breaker refused the chunk
+    and no launch failed, so nothing counts as degraded or retried."""
+    imgs = _route_payloads(route)
+    want = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0).segment(
+        imgs, method=route)
+    eng = _reference_engine(route, batch_sizes=(4,), cache_size=0,
+                            trace_ring=8)
+    got = eng.segment(imgs, method=route)
+    st = eng.stats()
+    assert st["compiled_programs"] == 0 and len(eng._ref_programs) == 1
+    ft = st["fault_tolerance"]
+    assert ft["breaker_state"][route] == "open"
+    assert ft["degraded"][route] == 0 and ft["retries"][route] == 0
+    bucket = _last_bucket(eng, route)
+    assert bucket["attrs"]["fused"] is False
+    assert [c["name"] for c in bucket["children"]] == [
+        "gather", "launch", "scatter"]
+    assert st["stage_seconds"][route]["solve"] > 0
+    assert st["convergence"][route]["lanes"] == len(imgs)
+    for w, g in zip(want, got):
+        assert g.method == w.method == route
+        np.testing.assert_allclose(g.centers, w.centers, atol=1e-5)
+        assert g.n_iters == w.n_iters and g.converged == w.converged
+        assert g.labels.dtype == w.labels.dtype
+        assert (g.labels == w.labels).all()
+
+
+def test_superpixel_program_matches_fit_superpixel():
+    """The superpixel program (one dispatch for a batch's weighted fits
+    and per-superpixel labels; the padding lane replays lane 0) serves
+    each request what fit_superpixel gives its image."""
+    from repro.superpixel.pipeline import fit_superpixel
+
+    eng = FCMServeEngine(CFG, batch_sizes=(4,))
+    imgs = [phantom.phantom_slice_rgb(64, 64, noise=3.0 + 2 * i, seed=i)[0]
+            for i in range(3)]
+    res = eng.segment(imgs, method="superpixel")
+    s = eng.stats()
+    assert s["compiled_programs"] == 1
+    assert s["superpixel_batches"] == 1 and s["superpixel_padded_lanes"] == 1
+    for img, r in zip(imgs, res):
+        direct, _ = fit_superpixel(img.astype(np.float32),
+                                   eng.superpixel_cfg)
+        np.testing.assert_allclose(r.centers, np.asarray(direct.centers),
+                                   atol=1e-3)
+        assert r.n_iters == int(direct.n_iters)
+        assert r.labels.shape == img.shape[:2]
+        assert (r.labels == np.asarray(direct.labels)).all()
 
 
 def test_spatial_gather_stages_uint8_chunks_flat():
@@ -518,12 +592,10 @@ def test_spatial_staging_dtype_serves_what_float32_staging_serves(
     submitted as float32, and every dtype still matches the direct
     FCM_S fit. ``route.h2d_bytes{spatial}`` grows by exactly the staged
     bytes of each batch (3 lanes in a bucket of 4: padding included).
-    Labels come back as uint8 (c = 4), from the fused program and from
-    the staged path alike, with the same values; ``route.d2h_bytes``
+    Labels come back as uint8 (c = 4), from the fast program and from
+    the reference program alike, with the same values; ``route.d2h_bytes``
     grows by exactly the fetched outputs' bytes, the labels' a byte a
     pixel of every lane."""
-    from repro.serving import fcm_engine as E
-
     base = _noisy_slices()
     imgs = [img.astype(kinds[i % len(kinds)]) for i, img in enumerate(base)]
     eng = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
@@ -538,16 +610,15 @@ def test_spatial_staging_dtype_serves_what_float32_staging_serves(
     assert h2d.value == 2 * per_batch and d2h.value == 2 * fetched
     ref = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0).segment(
         [img.astype(np.float32) for img in base], method="spatial")
-    spatial = E.ROUTES["spatial"]
-    E.register_route(dataclasses.replace(spatial, program_key=None,
-                                         make_program=None))
-    try:
-        slow = FCMServeEngine(CFG, batch_sizes=(4,), cache_size=0)
-        res_staged = slow.segment(imgs, method="spatial")
-        assert slow.stats()["compiled_programs"] == 0
-    finally:
-        E.register_route(spatial)
-    for a, b, c, s in zip(got, again, ref, res_staged):
+    slow = _reference_engine("spatial", batch_sizes=(4,), cache_size=0)
+    res_ref = slow.segment(imgs, method="spatial")
+    assert slow.stats()["compiled_programs"] == 0
+    assert len(slow._ref_programs) == 1
+    assert slow.metrics.counter("route.h2d_bytes",
+                                route="spatial").value == per_batch
+    assert slow.metrics.counter("route.d2h_bytes",
+                                route="spatial").value == fetched
+    for a, b, c, s in zip(got, again, ref, res_ref):
         for r in (a, b):
             assert (r.centers == c.centers).all()
             assert r.n_iters == c.n_iters
@@ -600,9 +671,9 @@ def test_program_cache_evicts_on_route_reregistration():
     base = E.ROUTES["histogram"]
     calls = []
 
-    def make_program(e, key, bucket):
+    def make_program(e, key, bucket, **kw):
         calls.append(key)
-        return base.make_program(e, key, bucket)
+        return base.make_program(e, key, bucket, **kw)
 
     E.register_route(dataclasses.replace(base, make_program=make_program))
     try:
@@ -672,23 +743,30 @@ def test_histogram_materialize_lut_matches_labels_from_centers():
 
 
 def test_pixel_materialize_fused_labels_match_full_membership_path():
-    """Satellite: pixel-route labels via the fused argmin kernel path
-    equal the old materialize-the-membership-then-argmax path."""
+    """Pixel-route labels from the fast program (the fused argmin) equal
+    its reference program's and the full-membership argmax of the
+    served centers; the fused argmin kernel (interpret mode) agrees."""
     import jax.numpy as jnp
     from repro.kernels import ops as kops
 
-    rng = np.random.default_rng(1)
-    x = rng.uniform(0, 255, 4000).astype(np.float32)
-    v = np.sort(rng.uniform(10, 240, 4)).astype(np.float32)
-    old = np.asarray(F.defuzzify(F.update_membership(
-        jnp.asarray(x), jnp.asarray(v), 2.0)))
-    new = np.asarray(kops.defuzzify_labels(jnp.asarray(x), jnp.asarray(v)))
-    np.testing.assert_array_equal(new, old)
-    # and through the kernel itself (interpret mode)
-    kern = np.asarray(kops.defuzzify_labels_batched(
-        jnp.asarray(x)[None], jnp.asarray(v)[None],
-        impl="pallas", interpret=True))[0]
-    np.testing.assert_array_equal(kern, old)
+    imgs = [phantom.phantom_slice(40, 44, noise=3.0 + i, seed=20 + i)[0]
+            for i in range(3)]
+    fast = FCMServeEngine(CFG, batch_sizes=(4,)).segment(imgs,
+                                                          method="pixel")
+    ref = _reference_engine("pixel", batch_sizes=(4,)).segment(
+        imgs, method="pixel")
+    for img, f, r in zip(imgs, fast, ref):
+        np.testing.assert_allclose(f.centers, r.centers, atol=1e-5)
+        assert f.n_iters == r.n_iters
+        assert (f.labels == r.labels).all()
+        x = jnp.asarray(img.ravel(), jnp.float32)
+        full = np.asarray(F.defuzzify(F.update_membership(
+            x, jnp.asarray(f.centers), 2.0))).reshape(img.shape)
+        assert (f.labels == full).all()
+        kern = np.asarray(kops.defuzzify_labels_batched(
+            x[None], jnp.asarray(f.centers)[None],
+            impl="pallas", interpret=True))[0]
+        assert (kern.reshape(img.shape) == full).all()
 
 
 def test_uint8_zero_copy_ingest_matches_clipped_path():
@@ -711,8 +789,8 @@ def test_route_registration_roundtrip():
     base = E.ROUTES["histogram"]
     spec = E.RouteSpec(name="histogram-shadow", ingest=base.ingest,
                        bucket_key=base.bucket_key,
-                       build_problem=base.build_problem,
-                       materialize=base.materialize,
+                       program_key=base.program_key,
+                       make_program=base.make_program,
                        cacheable=False, stats_prefix="histogram_shadow")
     E.register_route(spec)
     try:

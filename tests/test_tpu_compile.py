@@ -5,10 +5,14 @@ each kernel for a chip that is described, not attached. Interpret-mode
 parity tests cannot see what the chip's compiler refuses (block layouts,
 1-D vectors, float iotas, scoped VMEM), so every kernel is compiled here
 at the serving shapes of ``chip_smoke.py`` (B=64 and B=1 slices of
-217x181) and the resident kernels also at their dispatch bounds.
+217x181) and the resident kernels also at their dispatch bounds. The
+serving routes' reference programs, which run no kernel, are compiled
+at bucket 64 too: a degraded chunk must not meet the chip's compiler
+for the first time when its fast program fails.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -124,3 +128,26 @@ def test_fused_partials_compiles(one_chip):
     m = (1 << 20) // 128
     _compile(one_chip, lambda x, w, v: KC.fused_partials_pallas(
         x, w, v, 2.0, 64), _f32(m, 128), _f32(m, 128), _f32(C))
+
+
+@pytest.mark.parametrize("route", ["histogram", "pixel", "spatial",
+                                   "superpixel"])
+def test_reference_program_compiles(one_chip, route):
+    """The launch a breaker-degraded or salvaged 64-lane bucket of
+    217x181 slices runs: the route's program on the registry's
+    reference impls."""
+    from repro.serving import fcm_engine as E
+
+    k = GY * GX
+    key, shapes = {
+        "histogram": (("px", N), [((64, N), jnp.uint8), _f32(64, 256)]),
+        "pixel": (("px", H, W), [_f32(64, N)]),
+        "spatial": (("sp", np.dtype(np.uint8), H, W),
+                    [((64, N), jnp.uint8)]),
+        "superpixel": (("spx", k, 3), [_f32(64, k, 3), _f32(64, k)]),
+    }[route]
+    prog = E.ROUTES[route].make_program(E.FCMServeEngine(), key, 64,
+                                        reference=True)
+    assert prog.impls and all(i.endswith("/reference") for i in prog.impls)
+    prog.launch.lower(*[jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                        for s, dt in shapes]).compile()
